@@ -1,10 +1,12 @@
-"""End-to-end REAL-execution driver: a tiny JAX LM served with continuous
+"""End-to-end REAL-execution example: a JAX LM served with continuous
 batching + real IVF retrieval through the HedraRAG scheduler (wall-clock).
 
 Everything actually executes: prompts are tokenised (toy byte tokenizer),
-the GenerationEngine decodes real tokens from a randomly-initialised reduced
-qwen3 model, retrieval runs against the IVF index with the hot-cluster cache
-(jnp kernel-ref path), and the wavefront scheduler coordinates both.
+the GenerationEngine decodes real tokens from randomly-initialised qwen3-1.7b
+weights at its published widths (``--smoke``: the tiny same-family config),
+retrieval runs against the IVF index with the hot-cluster cache (the Pallas
+scan on a TPU, its jnp oracle elsewhere), and the wavefront scheduler
+coordinates both.
 
 Run:  PYTHONPATH=src python examples/serve_rag_e2e.py
       PYTHONPATH=src python examples/serve_rag_e2e.py --crossreq   # + the
@@ -18,12 +20,11 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
-import jax
 import numpy as np
 
 from repro.configs import get_config
 from repro.core.backends import RealBackend
-from repro.models import lm
+from repro.launch.serve import init_params
 from repro.retrieval import (
     CorpusConfig,
     HybridRetrievalEngine,
@@ -49,7 +50,8 @@ def main(argv=None) -> None:
                          "routing knobs)")
     ap.add_argument("--n-requests", type=int, default=8)
     ap.add_argument("--smoke", action="store_true",
-                    help="tiny shapes for the example smoke test")
+                    help="tiny model and corpus for the example smoke test "
+                         "(CPU)")
     args = ap.parse_args(argv)
 
     n_docs, n_clusters, max_len = (2_000, 12, 96) if args.smoke else (8_000, 32, 192)
@@ -57,13 +59,14 @@ def main(argv=None) -> None:
                                                n_topics=64))
     index = IVFIndex.build(docs, n_clusters=n_clusters, iters=4)
     embedder = SyntheticEmbedder(topics)
-    hybrid = HybridRetrievalEngine(index, cache_capacity=8, update_interval=10,
-                                   kernel_impl="ref")
+    hybrid = HybridRetrievalEngine(index, cache_capacity=8, update_interval=10)
 
-    cfg = get_config("qwen3-1.7b").reduced()
-    params = lm.init_params(cfg, jax.random.PRNGKey(0))
-    engine = GenerationEngine(cfg, params, max_batch=8, max_len=max_len,
-                              eos_id=0)
+    cfg = get_config("qwen3-1.7b")
+    if args.smoke:
+        cfg = cfg.reduced()
+    engine = GenerationEngine(cfg, init_params(cfg), max_batch=8,
+                              max_len=max_len, eos_id=0)
+    engine.warmup(max_new=24)  # compile before the timed run
 
     backend = RealBackend(engine, index, embedder, hybrid=hybrid)
 

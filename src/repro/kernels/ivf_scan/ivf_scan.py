@@ -32,22 +32,28 @@ from jax.experimental.pallas import tpu as pltpu
 
 f32 = jnp.float32
 BIG = 3.0e38  # plain python float: jnp constants may not be closure-captured
+BIG_INT = 2**30
 
 
-def _kpass_select(d2: jax.Array, base_idx: jax.Array, k: int):
-    """Top-k smallest of d2 (QB, M) -> (vals (QB, k), idx (QB, k))."""
-    QB, M = d2.shape
-    vals, idxs = [], []
+def _kpass_select(d2: jax.Array, pos: jax.Array, payload: jax.Array, k: int):
+    """Top-k smallest of d2 (QB, M), ties to the lowest ``pos``.
+
+    Returns (vals (QB, k), payload at the selected positions (QB, k)).  The
+    payload is picked with a masked min rather than a gather: Mosaic has no
+    lowering for an in-kernel ``take_along_axis``.
+    """
+    vals, outs = [], []
     work = d2
     for _ in range(k):
         m = jnp.min(work, axis=1, keepdims=True)  # (QB, 1)
-        is_min = work <= m
-        cand = jnp.where(is_min, base_idx, jnp.int32(2**30))
+        cand = jnp.where(work <= m, pos, BIG_INT)
         sel = jnp.min(cand, axis=1, keepdims=True)  # first argmin
+        hit = pos == sel
         vals.append(m)
-        idxs.append(sel)
-        work = jnp.where(base_idx == sel, BIG, work)
-    return jnp.concatenate(vals, axis=1), jnp.concatenate(idxs, axis=1)
+        outs.append(jnp.min(jnp.where(hit, payload, BIG_INT), axis=1,
+                            keepdims=True))
+        work = jnp.where(hit, BIG, work)
+    return jnp.concatenate(vals, axis=1), jnp.concatenate(outs, axis=1)
 
 
 def _ivf_scan_kernel(
@@ -81,22 +87,23 @@ def _ivf_scan_kernel(
     # squared L2 via MXU matmul + norms
     qn = jnp.sum(q * q, axis=1, keepdims=True)          # (QB, 1)
     tn = jnp.sum(tile * tile, axis=1)[None, :]          # (1, LB)
+    # HIGHEST: the MXU's default f32 pass rounds operands to bf16, which
+    # reorders near-tied candidates against an exact (float64) scan
     d2 = qn - 2.0 * jax.lax.dot_general(
-        q, tile, (((1,), (1,)), ((), ())), preferred_element_type=f32
+        q, tile, (((1,), (1,)), ((), ())), preferred_element_type=f32,
+        precision=lax.Precision.HIGHEST,
     ) + tn                                              # (QB, LB)
 
     nvalid = valid_ref[group_cluster[g]]
     col = jax.lax.broadcasted_iota(jnp.int32, d2.shape, 1) + j * lb
     d2 = jnp.where(col < nvalid, d2, BIG)
 
-    bv, bi = _kpass_select(d2, col, k)                  # block top-k
+    bv, bi = _kpass_select(d2, col, col, k)             # block top-k
     # merge with running scoreboard: k-pass over the 2k candidates
     cat_d = jnp.concatenate([best_d[...], bv], axis=1)  # (QB, 2k)
     cat_i = jnp.concatenate([best_i[...], bi], axis=1)
-    QB = cat_d.shape[0]
     pos = jax.lax.broadcasted_iota(jnp.int32, cat_d.shape, 1)
-    md, mp = _kpass_select(cat_d, pos, k)
-    mi = jnp.take_along_axis(cat_i, mp, axis=1)
+    md, mi = _kpass_select(cat_d, pos, cat_i, k)
     best_d[...] = md
     best_i[...] = mi
 

@@ -10,15 +10,18 @@ from repro.kernels.ivf_scan.ivf_scan import ivf_scan_pallas
 from repro.kernels.ivf_scan.ref import ivf_scan_ref
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def resolve_impl(impl: str) -> str:
+    """What ``impl`` runs as on this backend: ``auto`` is the Pallas kernel
+    on a TPU and the jnp oracle elsewhere."""
+    if impl == "auto":
+        return "pallas" if jax.default_backend() == "tpu" else "ref"
+    return impl
 
 
 @functools.partial(jax.jit, static_argnames=("k", "impl"))
 def ivf_scan(q_groups, group_cluster, slab, valid, k: int, *, impl: str = "auto"):
     """impl: auto | pallas | interpret | ref.  See ivf_scan.py for semantics."""
-    if impl == "auto":
-        impl = "pallas" if _on_tpu() else "ref"
+    impl = resolve_impl(impl)
     if impl == "pallas":
         return ivf_scan_pallas(q_groups, group_cluster, slab, valid, k)
     if impl == "interpret":

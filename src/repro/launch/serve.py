@@ -1,16 +1,25 @@
-"""Production serving launcher: sharded decode over a mesh + HedraRAG
-scheduler.  On this container it runs reduced configs on the host mesh; the
-production path is exercised compile-only via launch/dryrun.py.
+"""Serving launcher: the JAX generation engine and the hybrid retrieval
+engine behind the HedraRAG wavefront scheduler, on one device.
+
+The model is built at its published widths with random weights from
+``PRNGKey(0)``; ``--reduced`` swaps in the tiny same-family config for CPU
+runs.  Retrieval kernels resolve to Pallas on a TPU and to the jnp oracle
+elsewhere.  ``chip_smoke.py`` at the repository root drives ``build_server``
+on one TPU chip.
 """
 from __future__ import annotations
 
 import argparse
+import os
 import time
+from pathlib import Path
+from typing import Optional
 
 import jax
 import numpy as np
 
 from repro.configs import ARCH_IDS, get_config
+from repro.configs.base import ModelConfig
 from repro.core.backends import RealBackend
 from repro.models import lm
 from repro.retrieval import (
@@ -24,10 +33,31 @@ from repro.server import Server
 from repro.serving.engine import GenerationEngine
 from repro import workflows
 
+CHECKOUT = Path(__file__).resolve().parents[3]
 
-def main() -> None:
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
+    nothing is set here.  Otherwise the cache goes to ``<checkout>/.jax_cache``:
+    a fixed path, since the path is part of what a later run must find again.
+    Call it from an entry point before the first ``jit``, never on import.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def make_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-1.7b", choices=list(ARCH_IDS))
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the tiny same-family config (CPU runs) "
+                         "instead of the published widths")
     ap.add_argument("--n-requests", type=int, default=8)
     ap.add_argument("--workflow", default="one-shot",
                     choices=list(workflows.WORKFLOWS))
@@ -52,9 +82,11 @@ def main() -> None:
                     help="serve through the threaded wall-clock ingress "
                          "(serving/ingress.py) instead of the batch path; "
                          "arrivals are real producer-thread timestamps")
-    ap.add_argument("--speedup", type=float, default=200.0,
+    ap.add_argument("--speedup", type=float, default=1.0,
                     help="wall->virtual clock compression for --wallclock "
-                         "(1 wall ms = speedup virtual ms)")
+                         "(1 wall ms = speedup virtual ms).  The backend "
+                         "charges measured time, so 1 keeps heartbeats on "
+                         "the same clock as the work they interleave with")
     ap.add_argument("--closed-loop", type=int, default=0, metavar="CLIENTS",
                     help="with --wallclock: closed-loop load generation with "
                          "this many client threads (submit, wait, think, "
@@ -76,11 +108,70 @@ def main() -> None:
                     help="sample the labeled metrics registry and write the "
                          "JSON snapshot (with an embedded Prometheus text "
                          "exposition) here (implies telemetry=True)")
-    args = ap.parse_args()
+    return ap
 
+
+def model_config(args) -> ModelConfig:
+    cfg = get_config(args.arch)
+    return cfg.reduced() if args.reduced else cfg
+
+
+def init_params(cfg: ModelConfig):
+    """Random weights from ``PRNGKey(0)``, made on the device in the
+    config's dtype: under ``jit`` each float32 draw fuses into its cast, so
+    no float32 copy of a weight is held."""
+    return jax.jit(lm.init_params, static_argnums=0)(cfg, jax.random.PRNGKey(0))
+
+
+def build_server(args, cfg: ModelConfig, params, *, fault_plan=None,
+                 cache_update_interval: int = 50) -> Server:
+    """The whole serving stack for one pass: seeded synthetic corpus, IVF
+    index, hybrid retrieval engine with an 8-cluster device slab, a warmed
+    generation engine, the measured ``RealBackend`` and the hedra ``Server``.
+
+    Rebuilt from scratch for each pass (the replay oracle needs a fresh,
+    bit-identical stack: engine KV state and the hybrid cache are mutated by
+    a run).  The engine's prefill widths and decode step are compiled here,
+    so no compilation lands in the timed window.
+    """
     docs, _, topics = make_corpus(CorpusConfig(n_docs=8000, dim=48, n_topics=64))
-    cfg = get_config(args.arch).reduced()
-    params = lm.init_params(cfg, jax.random.PRNGKey(0))
+    index = IVFIndex.build(docs, n_clusters=32, iters=4)
+    embedder = SyntheticEmbedder(topics)
+    hybrid = HybridRetrievalEngine(index, cache_capacity=8,
+                                   update_interval=cache_update_interval)
+    engine = GenerationEngine(cfg, params, max_batch=8, max_len=160,
+                              eos_id=0)
+    engine.warmup(args.max_new)
+    backend = RealBackend(engine, index, embedder, hybrid=hybrid)
+    pending = [f"query {i}" for i in range(args.n_requests)]
+    orig = backend.gen_duration
+
+    def gen_duration(n_prefill_tokens, batch, n_steps):
+        while engine.can_admit() and pending:
+            p = pending.pop(0)
+            toks = (np.frombuffer(p.encode(), np.uint8).astype(np.int32)
+                    % (cfg.vocab_size - 2)) + 1
+            engine.add_sequence(toks, max_new=args.max_new)
+        return orig(n_prefill_tokens, batch, n_steps)
+
+    backend.gen_duration = gen_duration
+    return Server(index, embedder, mode="hedra", backend=backend,
+                  nprobe=8,
+                  num_ret_workers=args.ret_workers,
+                  dispatch_policy=args.dispatch,
+                  index_sharding=args.index_sharding,
+                  fault_plan=fault_plan,
+                  external_heartbeats=args.wallclock,
+                  fault_tolerance=args.wallclock,
+                  tracing=args.trace_out is not None,
+                  telemetry=args.metrics_out is not None)
+
+
+def main(argv: Optional[list[str]] = None) -> None:
+    args = make_parser().parse_args(argv)
+    enable_compile_cache()
+    cfg = model_config(args)
+    params = init_params(cfg)
     fault_plan = None
     if args.fault_seed is not None:
         from repro.serving.faults import FaultPlan
@@ -92,41 +183,10 @@ def main() -> None:
             transient_prob=args.fault_transient_prob)
         print(f"fault plan: {fault_plan.describe()}")
 
-    def build_server() -> Server:
-        # rebuilt from scratch for each serving pass (the replay oracle
-        # needs a fresh, bit-identical stack: engine KV state and the
-        # hybrid cache are mutated by a run)
-        index = IVFIndex.build(docs, n_clusters=32, iters=4)
-        embedder = SyntheticEmbedder(topics)
-        hybrid = HybridRetrievalEngine(index, cache_capacity=8,
-                                       kernel_impl="ref")
-        engine = GenerationEngine(cfg, params, max_batch=8, max_len=160,
-                                  eos_id=0)
-        backend = RealBackend(engine, index, embedder, hybrid=hybrid)
-        pending = [f"query {i}" for i in range(args.n_requests)]
-        orig = backend.gen_duration
+    def build() -> Server:
+        return build_server(args, cfg, params, fault_plan=fault_plan)
 
-        def gen_duration(n_prefill_tokens, batch, n_steps):
-            while engine.can_admit() and pending:
-                p = pending.pop(0)
-                toks = (np.frombuffer(p.encode(), np.uint8).astype(np.int32)
-                        % (cfg.vocab_size - 2)) + 1
-                engine.add_sequence(toks, max_new=args.max_new)
-            return orig(n_prefill_tokens, batch, n_steps)
-
-        backend.gen_duration = gen_duration
-        return Server(index, embedder, mode="hedra", backend=backend,
-                      nprobe=8,
-                      num_ret_workers=args.ret_workers,
-                      dispatch_policy=args.dispatch,
-                      index_sharding=args.index_sharding,
-                      fault_plan=fault_plan,
-                      external_heartbeats=args.wallclock,
-                      fault_tolerance=args.wallclock,
-                      tracing=args.trace_out is not None,
-                      telemetry=args.metrics_out is not None)
-
-    server = build_server()
+    server = build()
     t0 = time.perf_counter()
     if args.wallclock:
         from repro.serving import ingress
@@ -158,7 +218,7 @@ def main() -> None:
             trace.save(args.arrivals_out)
             print(f"arrival trace written to {args.arrivals_out}")
         if args.replay_check:
-            replica = build_server()
+            replica = build()
             ingress.tape_backend(replica.backend, tape, mode="replay")
             ingress.replay_trace(replica, trace)
             if replica.fingerprints() != server.fingerprints():

@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 f32 = jnp.float32
 
@@ -163,12 +162,12 @@ def make_sharded_search(mesh: Mesh, k: int, axis: str = "data"):
         neg, sel = jax.lax.top_k(-d_flat, k)
         return -neg, jnp.take_along_axis(r_flat, sel, axis=1)
 
-    inner = shard_map(
+    inner = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(), P(axis, None, None), P(axis)),
         out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(inner)
 

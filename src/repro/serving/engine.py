@@ -8,8 +8,10 @@ to bound recompilation) whose state is scattered into a free slot, and leave
 when EOS/max-token hits, freeing the slot for the next request: continuous
 batching.
 
-This engine is what RealBackend binds to; the multi-pod serving path jits
-the same ``decode_step`` over the production mesh (launch/serve.py).
+This engine is what RealBackend binds to (launch/serve.py).  Its jitted
+programs are module-level and keyed on the config, so engines built for the
+same model share compiled code, and ``warmup`` compiles every program a
+serving pass can reach before the pass is timed.
 """
 from __future__ import annotations
 
@@ -43,6 +45,32 @@ def _bucket(n: int, buckets=(32, 64, 128, 256, 512, 1024, 2048)) -> int:
     return -(-n // 2048) * 2048
 
 
+def _decode_impl(params, state, tokens, key, active, *, cfg, sampler):
+    logits, state = lm.decode_step(params, cfg, tokens, state)
+    nxt = sample(logits, key, sampler)
+    # frozen slots keep emitting pad; their cache_len must not grow
+    state["cache_len"] = jnp.where(active, state["cache_len"],
+                                   state["cache_len"] - 1)
+    return nxt, state
+
+
+def _insert_impl(slab_state, one_state, slot):
+    def ins(slab, one):
+        if slab.ndim == 1:  # cache_len (B,)
+            return slab.at[slot].set(one[0])
+        # (L, B, ...) vs (L, 1, ...)
+        return jax.lax.dynamic_update_slice_in_dim(slab, one.astype(slab.dtype), slot, axis=1)
+
+    return jax.tree.map(ins, slab_state, one_state)
+
+
+# (params, cfg, tokens (B, S), *, max_len) -> (last-token logits (B, V), state)
+jit_prefill = jax.jit(lm.prefill, static_argnums=(1,), static_argnames=("max_len",))
+_decode = jax.jit(_decode_impl, static_argnames=("cfg", "sampler"),
+                  donate_argnums=(1,))
+_insert = jax.jit(_insert_impl, donate_argnums=(0,))
+
+
 class GenerationEngine:
     def __init__(self, cfg: ModelConfig, params, *, max_batch: int = 8,
                  max_len: int = 512, eos_id: int = 0,
@@ -54,37 +82,46 @@ class GenerationEngine:
         self.eos_id = eos_id
         self.sampler = sampler if sampler is not None else SamplerConfig()
         self.state = lm.init_decode_state(cfg, max_batch, max_len)
-        self.free_slots = list(range(max_batch))
         self.seqs: dict[int, Sequence] = {}
-        self._next_id = 0
         self._key = jax.random.PRNGKey(seed)
-        self._last_tokens = jnp.zeros((max_batch,), jnp.int32)
-        self._active = np.zeros((max_batch,), bool)
+        self._clear_slots()
 
-        self._prefill = jax.jit(
-            lambda p, t: lm.prefill(p, cfg, t, max_len=max_len),
-            static_argnames=(),
-        )
-        self._decode = jax.jit(self._decode_impl, donate_argnums=(1,))
-        self._insert = jax.jit(self._insert_impl, donate_argnums=(0,))
+    def _clear_slots(self) -> None:
+        self.free_slots = list(range(self.max_batch))
+        self.seqs.clear()
+        self._next_id = 0
+        self._last_tokens = jnp.zeros((self.max_batch,), jnp.int32)
+        self._active = np.zeros((self.max_batch,), bool)
 
-    # ------------------------------------------------------------- internals
-    def _decode_impl(self, params, state, tokens, key, active):
-        logits, state = lm.decode_step(params, self.cfg, tokens, state)
-        nxt = sample(logits, key, self.sampler)
-        # frozen slots keep emitting pad; their cache_len must not grow
-        state["cache_len"] = jnp.where(active, state["cache_len"],
-                                       state["cache_len"] - 1)
-        return nxt, state
+    def _prompt_room(self, max_new: int) -> int:
+        """Longest prompt kept for a sequence that may decode ``max_new``."""
+        # decode writes land at cache_len, so the padded prompt width plus
+        # the decode cap must fit the cache or late steps clamp at max_len
+        # and corrupt the last KV slot.  Reserve decode room for max_new
+        # (but at most half the cache — max_new is often a loose cap).
+        decode_room = min(max_new, max(self.max_len // 2, 1))
+        return max(self.max_len - decode_room, 1)
 
-    def _insert_impl(self, slab_state, one_state, slot):
-        def ins(slab, one):
-            if slab.ndim == 1:  # cache_len (B,)
-                return slab.at[slot].set(one[0])
-            # (L, B, ...) vs (L, 1, ...)
-            return jax.lax.dynamic_update_slice_in_dim(slab, one.astype(slab.dtype), slot, axis=1)
+    def prefill_widths(self, max_new: int) -> list[int]:
+        """Every padded prompt width ``add_sequence(.., max_new)`` can use:
+        one compiled prefill program each."""
+        keep = self._prompt_room(max_new)
+        return sorted({min(_bucket(n), keep) for n in range(1, keep + 1)})
 
-        return jax.tree.map(ins, slab_state, one_state)
+    def warmup(self, max_new: int) -> None:
+        """Compile every prefill width and the decode step, then return the
+        slots to their empty state, so that a timed serving pass whose
+        sequences use at most ``max_new`` compiles nothing.  The sampling key
+        is restored: a warmed engine generates what a cold one would."""
+        key = self._key
+        for w in self.prefill_widths(max_new):
+            if not self.free_slots:
+                self._clear_slots()
+            self.add_sequence(np.ones((w,), np.int32), max_new=max_new)
+        self.step()
+        jax.block_until_ready(self.state)
+        self._clear_slots()
+        self._key = key
 
     # ------------------------------------------------------------------ API
     def can_admit(self) -> bool:
@@ -95,14 +132,9 @@ class GenerationEngine:
         if not self.free_slots:
             raise RuntimeError("no free slots")
         slot = self.free_slots.pop()
-        # decode writes land at cache_len, so the padded prompt width plus
-        # the decode cap must fit the cache or late steps clamp at max_len
-        # and corrupt the last KV slot.  Reserve decode room for max_new
-        # (but at most half the cache — max_new is often a loose cap), keep
-        # the prompt suffix (left-pad semantics), and shrink the effective
-        # max_new to the headroom left after padding.
-        decode_room = min(max_new, max(self.max_len // 2, 1))
-        keep = max(self.max_len - decode_room, 1)
+        # keep the prompt suffix (left-pad semantics), and shrink the
+        # effective max_new to the headroom left after padding
+        keep = self._prompt_room(max_new)
         prompt_tokens = np.asarray(prompt_tokens)
         if len(prompt_tokens) > keep:
             prompt_tokens = prompt_tokens[-keep:]
@@ -111,8 +143,9 @@ class GenerationEngine:
         max_new = min(max_new, self.max_len - pad_to)
         toks = np.zeros((1, pad_to), np.int32)
         toks[0, pad_to - n:] = prompt_tokens  # left-pad (simplest causal-safe)
-        logits, st1 = self._prefill(self.params, jnp.asarray(toks))
-        self.state = self._insert(self.state, st1, slot)
+        logits, st1 = jit_prefill(self.params, self.cfg, jnp.asarray(toks),
+                                  max_len=self.max_len)
+        self.state = _insert(self.state, st1, slot)
         # note: left-padding slightly pollutes the prefix; acceptable for the
         # toy-model integration path (real deployment uses paged prefill)
         first = int(jnp.argmax(logits[0]))
@@ -131,8 +164,9 @@ class GenerationEngine:
             return {}
         self._key, sub = jax.random.split(self._key)
         active = jnp.asarray(self._active)
-        nxt, self.state = self._decode(self.params, self.state,
-                                       self._last_tokens, sub, active)
+        nxt, self.state = _decode(self.params, self.state, self._last_tokens,
+                                  sub, active, cfg=self.cfg,
+                                  sampler=self.sampler)
         self._last_tokens = nxt
         out: dict[int, int] = {}
         nxt_np = np.asarray(nxt)

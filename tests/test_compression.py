@@ -56,16 +56,15 @@ import sys
 sys.path.insert(0, sys.argv[1])
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 from repro.training.compression import compressed_psum_leaf
 
 mesh = jax.make_mesh((2,), ("pod",))
 rng = np.random.default_rng(2)
 x = jnp.asarray(rng.standard_normal((2, 515)) * 0.02, jnp.float32)
 
-f = shard_map(lambda v: compressed_psum_leaf(v[0], "pod"),
-              mesh=mesh, in_specs=(P("pod", None),), out_specs=P(None),
-              check_rep=False)
+f = jax.shard_map(lambda v: compressed_psum_leaf(v[0], "pod"),
+                  mesh=mesh, in_specs=(P("pod", None),), out_specs=P(None),
+                  check_vma=False)
 with mesh:
     got = f(x)
 want = np.asarray(x).sum(0)

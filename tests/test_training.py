@@ -152,3 +152,33 @@ def test_generation_engine_sampler_not_shared(tiny):
     e1 = GenerationEngine(cfg, params, max_batch=1, max_len=32)
     e2 = GenerationEngine(cfg, params, max_batch=1, max_len=32)
     assert e1.sampler is not e2.sampler
+
+
+def test_generation_engine_warmup_leaves_nothing_to_compile(tiny):
+    """After warmup, prompts of every length and decode compile nothing,
+    and the engine generates what an engine that was never warmed does."""
+    from repro.serving.engine import GenerationEngine, _decode, jit_prefill
+
+    cfg, params = tiny
+    max_new = 8
+    warm = GenerationEngine(cfg, params, max_batch=2, max_len=96, eos_id=-1)
+    warm.warmup(max_new)
+    assert warm.batch_size == 0 and len(warm.free_slots) == 2
+    sizes = (jit_prefill._cache_size(), _decode._cache_size())
+    for n in range(1, 100):
+        warm.add_sequence(np.arange(n) % 200 + 1, max_new=max_new)
+        warm.step()
+        warm._clear_slots()
+    assert (jit_prefill._cache_size(), _decode._cache_size()) == sizes
+
+    def generate(eng):
+        eng.add_sequence(np.arange(7) % 200 + 1, max_new=max_new)
+        sid = eng.add_sequence(np.arange(40) % 200 + 1, max_new=max_new)
+        seq = eng.seqs[sid]
+        while eng.batch_size:
+            eng.step()
+        return seq.tokens
+
+    warm.warmup(max_new)
+    cold = GenerationEngine(cfg, params, max_batch=2, max_len=96, eos_id=-1)
+    assert generate(warm) == generate(cold)
