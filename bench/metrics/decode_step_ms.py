@@ -1,0 +1,14 @@
+"""Mean wall time of one engine decode step in the window; each step ends
+in the engine's host pull of the new tokens."""
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(__file__))
+from _common import window_spans  # noqa: E402
+
+
+def read(ctx):
+    s = window_spans(ctx, "decode")
+    if not s:
+        return None
+    return 1e3 * sum(b - a for _, a, b, _ in s) / len(s)

@@ -1,0 +1,21 @@
+"""Published peaks per chip, keyed by JAX's ``device_kind``.
+
+A kind missing from the table is an error: a roofline share against a
+guessed peak is no measurement.
+"""
+from __future__ import annotations
+
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s
+    # int8, 16 GB HBM at 819 GB/s per chip.
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9,
+                    "hbm_bytes": 16e9},
+}
+
+
+def peaks_for(device_kind: str) -> dict:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}") from None
