@@ -8,8 +8,10 @@ Three rules:
 * ``determinism/wall-clock`` — ``time.time``/``perf_counter``/
   ``datetime.now`` and friends inside the virtual-clock zone
   (core/serving/crossreq/obs), where the event clock is the only legal
-  time source.  RealBackend's measured-execution path is the sanctioned
-  exception, carried as inline suppressions with justification.
+  time source.  The tracer's one clock read (``obs/trace.py``), which
+  times the wall-clock spans and RealBackend's measured charges, is the
+  sanctioned exception, carried as an inline suppression with
+  justification.
 * ``determinism/set-iteration`` — iterating a ``set``/``frozenset`` leaks
   hash order into whatever the loop does; inside the scheduling packages
   that is an ordering bug waiting for a string key.  Iterations wrapped in

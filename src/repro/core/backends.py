@@ -14,11 +14,11 @@ used by the end-to-end examples and integration tests.
 """
 from __future__ import annotations
 
-import time
 from typing import Callable, Optional, Sequence
 
 import numpy as np
 
+from repro.obs.trace import TraceRecorder
 from repro.retrieval.hybrid import HybridRetrievalEngine
 from repro.retrieval.ivf import ClusterCostModel, IVFIndex, TopK
 from repro.retrieval.plan import RetrievalPlan
@@ -137,7 +137,7 @@ class SimBackend:
         def results_fn(work=tuple(work)) -> list:
             base = [(q, cid, TopK.empty(tk.k)) for q, cid, tk in work]
             if self.hybrid is not None:
-                res, _ = self.hybrid.search_substage(base)
+                res = self.hybrid.search_substage(base)
             else:
                 res = self.index.search_cluster_batch(base)
             return [(r.dists[r.ids >= 0], r.ids[r.ids >= 0]) for r in res]
@@ -269,6 +269,9 @@ class RealBackend:
         self.device_speedup = 8.0
         self.fault_plan = None  # chaos scripts target the simulated clock
         self._lexical = None
+        # each measured charge is the duration of a span of this recorder
+        # (the server's, once ``Server.wall_trace`` hands it over)
+        self.trace = TraceRecorder()
 
     def query_embedding(self, req, round_idx: int) -> np.ndarray:
         return self.embedder.embed_query(req.request_id, round_idx)
@@ -278,13 +281,13 @@ class RealBackend:
 
     def gen_duration(self, n_prefill_tokens: int, batch: int, n_steps: int) -> float:
         """Execute n_steps of real decoding on the engine; return measured us.
-        The scheduler passes the request set via bind_gen_batch beforehand."""
-        # RealBackend measures *actual* execution; the virtual clock only
-        # advances by these measured durations, so reading the wall clock
-        # here is the sanctioned boundary between real and virtual time.
-        t0 = time.perf_counter()  # repro-lint: disable=wall-clock
-        self.gen_engine.step_batch(n_steps)
-        return (time.perf_counter() - t0) * 1e6  # repro-lint: disable=wall-clock
+
+        RealBackend measures *actual* execution and the virtual clock only
+        advances by these measured durations: each charge is the duration
+        of the span that timed the call."""
+        with self.trace.span("engine.substage", n_steps=n_steps) as sp:
+            self.gen_engine.step_batch(n_steps)
+        return sp.dur_us
 
     def search_charged(self, work, worker_id: int = 0):
         if isinstance(work, RetrievalPlan):
@@ -301,22 +304,20 @@ class RealBackend:
                                      item_cost / self.device_speedup,
                                      item_cost)
                 self.fused_saved_us += float((item_cost * extra).sum())
-            # real-time measurement boundary (see gen_duration)
-            t0 = time.perf_counter()  # repro-lint: disable=wall-clock
-            batch = self.hybrid.search_plan(
-                work, owner=worker_id if self.hybrid.sharded else None)
-            measured = (time.perf_counter() - t0) * 1e6  # repro-lint: disable=wall-clock
+            with self.trace.span("ret.substage", worker=worker_id) as sp:
+                batch = self.hybrid.search_plan(
+                    work, owner=worker_id if self.hybrid.sharded else None)
+            measured = sp.dur_us
             self.worker_busy_us[worker_id] = (
                 self.worker_busy_us.get(worker_id, 0.0) + measured)
             return measured, lambda: batch
         if not work:
             return 0.0, lambda: []
-        # real-time measurement boundary (see gen_duration)
-        t0 = time.perf_counter()  # repro-lint: disable=wall-clock
-        base = [(q, cid, TopK.empty(tk.k)) for q, cid, tk in work]
-        res, timing = self.hybrid.search_substage(base)
-        out = [(r.dists[r.ids >= 0], r.ids[r.ids >= 0]) for r in res]
-        measured = (time.perf_counter() - t0) * 1e6  # repro-lint: disable=wall-clock
+        with self.trace.span("ret.substage", worker=worker_id) as sp:
+            base = [(q, cid, TopK.empty(tk.k)) for q, cid, tk in work]
+            res = self.hybrid.search_substage(base)
+            out = [(r.dists[r.ids >= 0], r.ids[r.ids >= 0]) for r in res]
+        measured = sp.dur_us
         self.worker_busy_us[worker_id] = (
             self.worker_busy_us.get(worker_id, 0.0) + measured)
         return measured, lambda: out
@@ -326,10 +327,9 @@ class RealBackend:
         measured time, hand completion a closure over the result."""
         if task.fanout > 1:
             self.fused_saved_us += float(task.cost_us) * (task.fanout - 1)
-        # real-time measurement boundary (see gen_duration)
-        t0 = time.perf_counter()  # repro-lint: disable=wall-clock
-        result = task.execute()
-        measured = (time.perf_counter() - t0) * 1e6  # repro-lint: disable=wall-clock
+        with self.trace.span("stage.run", worker=worker_id) as sp:
+            result = task.execute()
+        measured = sp.dur_us
         self.worker_busy_us[worker_id] = (
             self.worker_busy_us.get(worker_id, 0.0) + measured)
         return measured, lambda: result

@@ -35,6 +35,7 @@ from repro.core.similarity import LocalCache
 from repro.core.speculation import SpeculationPolicy, Speculator
 from repro.core.substage import TimeBudget
 from repro.core import transforms
+from repro.obs.trace import NOSPAN, TraceRecorder
 from repro.retrieval.ivf import TopK
 from repro.retrieval.plan import (
     BatchTopK,
@@ -439,8 +440,20 @@ class _FaultState:
     orphan_parts: list = dataclasses.field(default_factory=list)
 
 
+def _plan_rids(plan) -> list:
+    """Request ids a retrieval plan serves (speculative warm-up groups are
+    background work and serve none)."""
+    rids = []
+    for meta in plan.group_meta:
+        if meta[0] in ("ret", "stage"):
+            rids.append(meta[1].request_id)
+        elif meta[0] == "shard":
+            rids.append(meta[1].req.request_id)
+    return sorted(set(rids))
+
+
 @owned_by("scheduler", expose=("metrics", "crossreq", "obs", "telemetry",
-                               "lifecycle", "shard_map"))
+                               "trace", "lifecycle", "shard_map"))
 class WavefrontScheduler:
     def __init__(self, backend, index, config: SchedulerConfig,
                  workload=None):
@@ -508,14 +521,15 @@ class WavefrontScheduler:
             self.ft = _FaultState(plan=fault_plan)
         self.metrics = Metrics()
         self.metrics.ret_busy_per_worker = [0.0] * self.num_ret_workers
-        # observability taps (obs/): lazily imported so the default path
-        # never loads the package; both are purely passive recorders
+        # observability taps (obs/): purely passive recorders.  ``trace`` is
+        # the recorder whose wall-clock channel times the served path (off
+        # until switched); with tracing on it is the virtual-clock recorder
+        # too, so one recorder carries both clocks
         self.obs = None
         self.telemetry = None
         if config.tracing:
-            from repro.obs.trace import TraceRecorder
-
             self.obs = TraceRecorder()
+        self.trace = self.obs if self.obs is not None else TraceRecorder()
         if config.telemetry:
             from repro.obs.registry import TelemetrySampler
 
@@ -876,7 +890,14 @@ class WavefrontScheduler:
             self.workload.prompt_tokens(r.request_id, r.current or 0)
             for r in batch if not r.gen.prefilled
         )
-        dur = self.backend.gen_duration(n_prefill_tokens, len(batch), n_steps)
+        tr = self.trace
+        with (tr.span("sched.gen_substage",
+                      rids=[r.request_id for r in batch], n_steps=n_steps,
+                      budget_us=int(self.budget.mb_us),
+                      prefill_tokens=n_prefill_tokens)
+              if tr.wall else NOSPAN):
+            dur = self.backend.gen_duration(n_prefill_tokens, len(batch),
+                                            n_steps)
         dur = self._mitigate_straggler(dur, expected=dur)
         for r in batch:
             r.gen.engine_seq = "inflight"
@@ -897,12 +918,18 @@ class WavefrontScheduler:
                           tasks=(), hedge_tokens=None) -> dict:
         charge = 0.0
         results_fn = None
+        tr = self.trace
         if plan is not None:
-            charge, results_fn = self.backend.search_charged(plan,
-                                                             worker_id=wid)
+            with (tr.span("sched.ret_substage", rids=_plan_rids(plan),
+                          worker=wid)
+                  if tr.wall else NOSPAN):
+                charge, results_fn = self.backend.search_charged(
+                    plan, worker_id=wid)
         task_runs = []
         for t in tasks:
-            c, fn = self.backend.stage_charged(t, worker_id=wid)
+            with (tr.span("sched.stage", rids=[t.req.request_id], worker=wid)
+                  if tr.wall else NOSPAN):
+                c, fn = self.backend.stage_charged(t, worker_id=wid)
             charge += c
             task_runs.append((t, fn))
         dur = self._mitigate_straggler(charge, expected=charge, worker_id=wid)
@@ -1899,6 +1926,9 @@ class WavefrontScheduler:
             self._prime_probe_orders(admitted, now)
             for req in admitted:
                 self._enter_stage(req, now)
+            if self.trace.wall:
+                for req in admitted:
+                    self.trace.mark("sched.admit", rid=req.request_id)
         # speculation decisions on the current wavefront
         if self.cfg.speculation.enabled:
             self._maybe_spec_generation(now)
@@ -2007,7 +2037,9 @@ class WavefrontScheduler:
             guard += 1
             if guard > 5_000_000:
                 raise RuntimeError("scheduler stuck — no progress")
-            status = self._cycle(hard_cutoff=max_time_us)
+            with (self.trace.span("sched.cycle") if self.trace.wall
+                  else NOSPAN):
+                status = self._cycle(hard_cutoff=max_time_us)
             if status in ("done", "cutoff"):
                 break
         return self._finalize_metrics()
@@ -2033,7 +2065,9 @@ class WavefrontScheduler:
             guard += 1
             if guard > 5_000_000:
                 raise RuntimeError("scheduler stuck — no progress")
-            status = self._cycle(horizon=until)
+            with (self.trace.span("sched.cycle") if self.trace.wall
+                  else NOSPAN):
+                status = self._cycle(horizon=until)
             if status != "advanced":
                 break
             if self.now >= until:

@@ -22,8 +22,10 @@ def resolve_impl(impl: str) -> str:
 def ivf_scan(q_groups, group_cluster, slab, valid, k: int, *, impl: str = "auto"):
     """impl: auto | pallas | interpret | ref.  See ivf_scan.py for semantics."""
     impl = resolve_impl(impl)
-    if impl == "pallas":
-        return ivf_scan_pallas(q_groups, group_cluster, slab, valid, k)
-    if impl == "interpret":
-        return ivf_scan_pallas(q_groups, group_cluster, slab, valid, k, interpret=True)
-    return ivf_scan_ref(q_groups, group_cluster, slab, valid, k)
+    with jax.named_scope("ivf_scan"):
+        if impl == "pallas":
+            return ivf_scan_pallas(q_groups, group_cluster, slab, valid, k)
+        if impl == "interpret":
+            return ivf_scan_pallas(q_groups, group_cluster, slab, valid, k,
+                                   interpret=True)
+        return ivf_scan_ref(q_groups, group_cluster, slab, valid, k)

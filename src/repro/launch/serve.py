@@ -102,8 +102,9 @@ def make_parser() -> argparse.ArgumentParser:
                     help="with --wallclock: write the recorded "
                          "arrival/heartbeat trace JSON here")
     ap.add_argument("--trace-out", metavar="PATH", default=None,
-                    help="record spans and write a Chrome trace-event / "
-                         "Perfetto JSON timeline here (implies tracing=True)")
+                    help="record spans on the virtual and the wall clock "
+                         "and write a Chrome trace-event / Perfetto JSON "
+                         "timeline here (implies tracing=True)")
     ap.add_argument("--metrics-out", metavar="PATH", default=None,
                     help="sample the labeled metrics registry and write the "
                          "JSON snapshot (with an embedded Prometheus text "
@@ -187,6 +188,8 @@ def main(argv: Optional[list[str]] = None) -> None:
         return build_server(args, cfg, params, fault_plan=fault_plan)
 
     server = build()
+    if args.trace_out:
+        server.wall_trace()  # the exported trace carries both clocks
     t0 = time.perf_counter()
     if args.wallclock:
         from repro.serving import ingress

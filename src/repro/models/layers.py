@@ -364,7 +364,8 @@ def _scatter_time(cache: jax.Array, new: jax.Array, lengths: jax.Array) -> jax.A
     def upd(c, n, start):
         return lax.dynamic_update_slice_in_dim(c, n.astype(c.dtype), start, axis=0)
 
-    return jax.vmap(upd)(cache, new, lengths)
+    with jax.named_scope("kv_write"):
+        return jax.vmap(upd)(cache, new, lengths)
 
 
 # ---------------------------------------------------------------------------

@@ -137,11 +137,12 @@ def _apply_block(
 ):
     st_in = state or {}
     h = apply_norm(cfg, p["norm1"], x)
-    mix_out, mix_st = _MIXER_APPLY[seg.mixer](
-        cfg, seg, p["mixer"], h,
-        mode=mode, positions=positions, state=st_in.get("mixer"),
-        cache_len=cache_len, max_len=max_len,
-    )
+    with jax.named_scope("attn"):
+        mix_out, mix_st = _MIXER_APPLY[seg.mixer](
+            cfg, seg, p["mixer"], h,
+            mode=mode, positions=positions, state=st_in.get("mixer"),
+            cache_len=cache_len, max_len=max_len,
+        )
     x = x + mix_out
 
     new_state: dict = {}
@@ -161,9 +162,10 @@ def _apply_block(
             new_state["enc_kv"] = enc_kv  # carried through unchanged
 
     h = apply_norm(cfg, p["norm2"], x)
-    ffn_out, ffn_st = apply_ffn(
-        cfg, seg, p["ffn"], h, state=st_in.get("ffn"), mode=mode
-    )
+    with jax.named_scope("mlp"):
+        ffn_out, ffn_st = apply_ffn(
+            cfg, seg, p["ffn"], h, state=st_in.get("ffn"), mode=mode
+        )
     x = x + ffn_out
     if ffn_st is not None:
         new_state["ffn"] = ffn_st
@@ -257,10 +259,11 @@ def _run_segment(
 
 
 def _embed(cfg: ModelConfig, params: dict, tokens: jax.Array) -> jax.Array:
-    x = params["embed"][tokens].astype(dtype_of(cfg))
-    if cfg.embed_scale:
-        x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
-    return constrain(x, "dp", None, None)
+    with jax.named_scope("embed"):
+        x = params["embed"][tokens].astype(dtype_of(cfg))
+        if cfg.embed_scale:
+            x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+        return constrain(x, "dp", None, None)
 
 
 def _head_weights(cfg: ModelConfig, params: dict) -> jax.Array:
@@ -369,7 +372,8 @@ def prefill(params: dict, cfg: ModelConfig, tokens: jax.Array, *, max_len: int,
         cfg, params, tokens, mode="prefill",
         prefix_embeds=prefix_embeds, enc_embeds=enc_embeds, max_len=max_len,
     )
-    logits = (h[:, -1, :] @ _head_weights(cfg, params)).astype(f32)
+    with jax.named_scope("head"):
+        logits = (h[:, -1, :] @ _head_weights(cfg, params)).astype(f32)
     state = {
         "cache_len": jnp.full((B,), S + n_prefix, jnp.int32),
         "segments": tuple(states),
@@ -394,7 +398,8 @@ def decode_step(params: dict, cfg: ModelConfig, tokens: jax.Array, state: dict):
         )
         new_states.append(st2)
     h = apply_norm(cfg, params["final_norm"], x)
-    logits = (h[:, 0, :] @ _head_weights(cfg, params)).astype(f32)
+    with jax.named_scope("head"):
+        logits = (h[:, 0, :] @ _head_weights(cfg, params)).astype(f32)
     return logits, {"cache_len": cache_len + 1, "segments": tuple(new_states)}
 
 

@@ -8,7 +8,10 @@ changes a scheduling decision, an RNG draw, or a per-request event trace):
   trace-event / Perfetto JSON timeline: one track per resource (gen
   engine, each retrieval worker, the admission queue) with flow events for
   sub-stage dependencies, hedge duplicates, shard scatter/gather fan-out,
-  dedup leader→follower fusion, and failover re-dispatch.
+  dedup leader→follower fusion, and failover re-dispatch.  The same
+  recorder has a wall-clock channel (``Server.wall_trace()``): spans of the
+  served path on the host's clock, keyed by request and mirrored into the
+  JAX profiler's trace as ``repro.*`` annotations.
 * ``obs.registry`` — a labeled metrics registry (counters / gauges /
   histograms with ``worker`` / ``stage_kind`` / ``workflow`` /
   ``slo_class`` labels) layered around the load-bearing ``Metrics``
@@ -19,7 +22,8 @@ changes a scheduling decision, an RNG draw, or a per-request event trace):
   that decomposes each finished request into queueing, retrieval compute,
   generation compute, stage compute, merge, retry/hedge/failover overhead,
   and fault-recovery time — components sum to the measured latency by
-  construction.
+  construction; ``wall_breakdown`` splits the wall-clock latency of a
+  measured run into ingress wait, service and the wait between.
 
 Enable through the scheduler knobs (``tracing=True`` / ``telemetry=True``)
 and read through ``Server.export_trace()`` / ``Server.metrics_snapshot()``
@@ -29,10 +33,13 @@ from repro.obs.attribution import (  # noqa: F401
     ATTRIBUTION_COMPONENTS,
     attribute_request,
     attribution_report,
+    wall_breakdown,
 )
 from repro.obs.registry import MetricsRegistry, TelemetrySampler  # noqa: F401
 from repro.obs.trace import (  # noqa: F401
+    NOSPAN,
     TraceRecorder,
+    WallSpan,
     request_ids_in_trace,
     validate_trace,
 )

@@ -31,6 +31,11 @@ equals the measured latency by construction, up to float rounding.  The
 run-level report (``Server.attribution_report()``) verifies that residual
 against a relative tolerance and aggregates totals, fractions and the
 per-workflow bottleneck component.
+
+The virtual clock overlaps generation and retrieval; a measured backend
+runs them in series on the scheduler thread.  :func:`wall_breakdown` splits
+each request's latency on the recorder's wall clock instead: ingress wait,
+time in a backend call that serves it, and the wait between.
 """
 from __future__ import annotations
 
@@ -171,3 +176,61 @@ def attribution_report(recorder, *, check: bool = True,
         "rel_tol": rel_tol,
         "per_request": rows,
     }
+
+
+# wall spans of the scheduler thread's calls into the backend; each names
+# the requests it serves under ``rids``
+SERVING_SPANS = ("sched.gen_substage", "sched.ret_substage", "sched.stage")
+
+
+def _measure(intervals) -> int:
+    """Length of the union of ``(a, b)`` intervals."""
+    total, end = 0, None
+    for a, b in sorted(intervals):
+        if end is None or a > end:
+            total += b - a
+            end = b
+        elif b > end:
+            total += b - end
+            end = b
+    return total
+
+
+def wall_breakdown(recorder) -> dict:
+    """Per request the ingress loop saw finish, its wall latency split on
+    the recorder's clock (``perf_counter_ns``), in us:
+
+    ``ingress_us``     ingress stamp (``serve.queue`` start) to admission
+                       (``sched.admit``);
+    ``service_us``     time covered by a backend call that serves it
+                       (``SERVING_SPANS`` naming it in ``rids``);
+    ``stage_wait_us``  the part of admission to ``serve.done`` that no such
+                       call covers;
+    ``latency_us``     ingress stamp to ``serve.done``.
+
+    The three parts add up to the latency when every serving call lies
+    between admission and completion.  Requests without all three marks
+    (shed and re-admitted ones) are left out."""
+    stamp, admit, done = {}, {}, {}
+    serving: dict = {}
+    for sp in recorder.wall_spans:
+        if sp.name == "serve.queue":
+            stamp[sp.args["rid"]] = sp.t0
+        elif sp.name == "sched.admit":
+            admit.setdefault(sp.args["rid"], sp.t0)
+        elif sp.name == "serve.done":
+            done[sp.args["rid"]] = sp.t0
+        elif sp.name in SERVING_SPANS:
+            for rid in sp.args.get("rids", ()):
+                serving.setdefault(rid, []).append((sp.t0, sp.t1))
+    out = {}
+    for rid in sorted(set(stamp) & set(admit) & set(done)):
+        a, d = admit[rid], done[rid]
+        calls = serving.get(rid, [])
+        covered = _measure([(max(x, a), min(y, d)) for x, y in calls
+                            if min(y, d) > max(x, a)])
+        out[rid] = {"ingress_us": (a - stamp[rid]) / 1e3,
+                    "service_us": _measure(calls) / 1e3,
+                    "stage_wait_us": (d - a - covered) / 1e3,
+                    "latency_us": (d - stamp[rid]) / 1e3}
+    return out

@@ -26,11 +26,29 @@ open directly:
 The same record doubles as the input to ``obs.attribution``: every span
 contributes a categorized per-request interval (generation / retrieval /
 stage compute, merge, retry and fault-recovery wait gaps).
+
+**The wall-clock channel.**  The same recorder also times the served path
+on the host's clock (``time.perf_counter_ns``), off until its switch
+``wall`` is set (``Server.wall_trace()``).  A :class:`WallSpan` records its
+name, start and end, its thread, its parent (the span open on the same
+thread when it opened) and a few integer args, among them the request ids
+it serves; counters are recorded at the same sites.  While a span is open
+it is also a ``jax.profiler.TraceAnnotation("repro.<name>")``, so whenever
+the profiler runs the span sits on the device trace's clock too.  With the
+switch off a span site costs one attribute check: the site writes
+``with (tr.span(...) if tr.wall else NOSPAN):``, so no clock is read and
+nothing is allocated.  ``RealBackend``'s charges are the one exception:
+they enter their span unguarded, since the charge is its duration.
+``to_chrome()`` exports the wall spans on a process track of their own,
+next to the virtual-clock tracks.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import json
+import threading
+import time
 from typing import Optional
 
 from repro.core.ownership import handoff, owned_by
@@ -61,6 +79,74 @@ def _track_name(track: tuple) -> str:
 
 
 _PID = 1  # single virtual process: the server
+_WALL_PID = 2  # the wall-clock channel's process track
+
+
+def _now_ns() -> int:
+    """The tracer's one clock read: every wall span, mark and measured
+    charge takes its instants here."""
+    return time.perf_counter_ns()  # repro-lint: disable=wall-clock -- the wall channel and RealBackend's measured charges time real execution; no scheduling decision reads it
+
+
+class WallSpan:
+    """One interval of the served path on ``time.perf_counter_ns``.
+
+    ``parent`` is the ``sid`` of the span open on the same thread when this
+    one opened (-1 for none); ``args`` holds a few integers (request ids
+    under ``rids``).  A span built without a recorder only measures."""
+
+    __slots__ = ("name", "args", "t0", "t1", "tid", "sid", "parent",
+                 "_rec", "_ann")
+
+    def __init__(self, rec, name: str, args: dict):
+        self._rec = rec
+        self.name = name
+        self.args = args
+        self.t0 = self.t1 = 0
+        self.tid = self.sid = self.parent = -1
+
+    def __enter__(self) -> "WallSpan":
+        rec = self._rec
+        if rec is not None:
+            from jax.profiler import TraceAnnotation
+
+            stack = rec._stack()
+            self.parent = stack[-1].sid if stack else -1
+            self.sid = next(rec._sids)
+            self.tid = threading.get_ident()
+            stack.append(self)
+            self._ann = TraceAnnotation("repro." + self.name)
+            self._ann.__enter__()
+        self.t0 = _now_ns()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.t1 = _now_ns()
+        rec = self._rec
+        if rec is not None:
+            self._ann.__exit__(None, None, None)
+            rec._stack().pop()
+            rec.wall_spans.append(self)
+        return False
+
+    @property
+    def dur_us(self) -> float:
+        return (self.t1 - self.t0) / 1e3
+
+
+class _NoSpan:
+    """The shared stand-in a span site enters while the channel is off."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+
+NOSPAN = _NoSpan()
 
 
 @dataclasses.dataclass
@@ -87,7 +173,13 @@ class _ReqTrace:
 
 @owned_by("obs")
 class TraceRecorder:
-    def __init__(self):
+    def __init__(self, *, wall: bool = False):
+        # the wall-clock channel's switch, and what it recorded
+        self.wall = bool(wall)
+        self.wall_spans: list[WallSpan] = []
+        self.counters: list[tuple] = []  # (t_ns, name, {series: value})
+        self._sids = itertools.count()
+        self._tls = threading.local()  # per-thread stack of open spans
         self.spans: list[dict] = []
         self.instants: list[dict] = []
         self.flows: list[dict] = []
@@ -325,6 +417,77 @@ class TraceRecorder:
                       "lifecycle", {"worker": int(wid), "from": old,
                                     "to": new})
 
+    # ----------------------------------------------------------- wall clock
+    @handoff("*")
+    def set_wall(self, on: bool) -> None:
+        """Switch the wall-clock channel."""
+        self.wall = bool(on)
+
+    def _stack(self) -> list:
+        stack = getattr(self._tls, "stack", None)
+        if stack is None:
+            stack = self._tls.stack = []
+        return stack
+
+    @handoff("*")
+    def span(self, name: str, **args) -> WallSpan:
+        """A span that times its block (``dur_us``), recorded while the
+        channel is on.  A site that needs no duration enters ``NOSPAN``
+        instead while the channel is off."""
+        return WallSpan(self if self.wall else None, name, args)
+
+    @handoff("*")
+    def mark(self, name: str, t0_ns: Optional[int] = None, **args) -> None:
+        """Record a span that ends now: from ``t0_ns`` (a :meth:`stamp`
+        taken earlier, on any thread; such a span has no parent) or,
+        without one, of no length inside the span open on this thread."""
+        sp = WallSpan(None, name, args)
+        sp.t1 = _now_ns()
+        sp.t0 = sp.t1 if t0_ns is None else int(t0_ns)
+        sp.tid = threading.get_ident()
+        sp.sid = next(self._sids)
+        stack = self._stack()
+        sp.parent = stack[-1].sid if stack and t0_ns is None else -1
+        self.wall_spans.append(sp)
+
+    @handoff("*")
+    def stamp(self) -> int:
+        """The wall clock now, for a later :meth:`mark`."""
+        return _now_ns()
+
+    @handoff("*")
+    def count(self, name: str, **values) -> None:
+        """Record counter values (cumulative totals) at this instant."""
+        self.counters.append((_now_ns(), name, values))
+
+    def _wall_chrome(self) -> tuple[list, list, Optional[int]]:
+        """(metadata, body, base ns) of the wall channel: one process
+        track, one thread track per host thread, times in us from the
+        first recorded instant."""
+        if not self.wall_spans and not self.counters:
+            return [], [], None
+        base = min([s.t0 for s in self.wall_spans]
+                   + [c[0] for c in self.counters])
+        tids: dict[int, int] = {}
+        for s in sorted(self.wall_spans, key=lambda s: s.t0):
+            tids.setdefault(s.tid, len(tids))
+        meta = [{"ph": "M", "pid": _WALL_PID, "tid": 0, "ts": 0.0,
+                 "name": "process_name",
+                 "args": {"name": "hedrarag-server (wall clock)"}}]
+        for host_tid, t in tids.items():
+            meta.append({"ph": "M", "pid": _WALL_PID, "tid": t, "ts": 0.0,
+                         "name": "thread_name",
+                         "args": {"name": f"host thread {host_tid}"}})
+        body = [{"ph": "X", "pid": _WALL_PID, "tid": tids[s.tid],
+                 "ts": (s.t0 - base) / 1e3, "dur": max(s.t1 - s.t0, 0) / 1e3,
+                 "name": s.name, "cat": "wall",
+                 "args": dict(s.args, sid=s.sid, parent=s.parent)}
+                for s in self.wall_spans]
+        body += [{"ph": "C", "pid": _WALL_PID, "tid": 0,
+                  "ts": (t - base) / 1e3, "name": name, "args": dict(values)}
+                 for t, name, values in self.counters]
+        return meta, body, base
+
     # -------------------------------------------------------------- export
     def to_chrome(self) -> dict:
         """Render as Chrome trace-event JSON (Perfetto-compatible)."""
@@ -362,15 +525,19 @@ class TraceRecorder:
                              ts=f["src"][1]))
             body.append(dict(base, ph="f", bp="e", tid=_tid(f["dst"][0]),
                              ts=f["dst"][1]))
+        wall_meta, wall_body, wall_base = self._wall_chrome()
+        body += wall_body
         # stable global time sort keeps every per-track ts sequence monotone
         body.sort(key=lambda e: e["ts"])
         return {
-            "traceEvents": ev + body,
+            "traceEvents": ev + wall_meta + body,
             "displayTimeUnit": "ms",
             "otherData": {
                 "generator": "repro.obs.trace",
                 "n_requests": len(self.requests),
                 "clock": "virtual-us",
+                "wall_clock": "perf_counter us from wall_t0_ns",
+                "wall_t0_ns": wall_base,
             },
         }
 
@@ -383,7 +550,7 @@ class TraceRecorder:
 # Structural validation (used by tests, the CLI, and CI)
 # ---------------------------------------------------------------------------
 
-_ALLOWED_PH = {"M", "X", "i", "B", "E", "s", "f", "t"}
+_ALLOWED_PH = {"M", "X", "i", "B", "E", "s", "f", "t", "C"}
 
 
 def validate_trace(trace: dict) -> list[str]:

@@ -16,34 +16,27 @@ the whole slab (``stats()['uploads']`` reports full vs delta traffic).
 Clusters larger than the tile length are refused residency (they would be
 silently truncated on the device) and stay on the host path.
 
+``stats()`` counts the probes (items) each path scanned and their rows (a
+probe's rows are its cluster's vectors).  With the recorder's wall channel
+on (``trace``, see ``repro.obs.trace``) a sub-stage records its partition,
+slab upload, device scan (pack, wait, merge) and host scan as spans, and
+those counters at each sub-stage.
+
 The legacy per-item ``search_substage`` API is kept as a thin adapter over
 the plan executor.
 """
 from __future__ import annotations
 
-import dataclasses
-import time
 from typing import Optional, Sequence
 
 import numpy as np
 
+from repro.obs.trace import NOSPAN, TraceRecorder
 from repro.retrieval.hotcache import HotClusterCache, capacity_from_bytes
 from repro.retrieval.ivf import IVFIndex, TopK
 from repro.retrieval.plan import BatchTopK, RetrievalPlan, plan_from_work
 
 QB = 8  # queries per device work group (sublane-aligned)
-
-
-@dataclasses.dataclass
-class SubstageTiming:
-    host_us: float = 0.0
-    device_us: float = 0.0
-    n_host_items: int = 0
-    n_device_items: int = 0
-
-    @property
-    def overlapped_us(self) -> float:
-        return max(self.host_us, self.device_us)
 
 
 class HybridRetrievalEngine:
@@ -67,6 +60,7 @@ class HybridRetrievalEngine:
         self.kernel_impl = kernel_impl
         self.topk_default = topk_default
         sizes = index.cluster_sizes()
+        self._sizes = sizes
         self.tile_len = tile_len or max(128, int(-(-sizes.max() // 128) * 128))
         self._jnp = jnp
         self.cache_capacity = cache_capacity
@@ -91,6 +85,9 @@ class HybridRetrievalEngine:
         self._dirty_slots: set[int] = set()  # staged but not yet delta-uploaded
         self._qbuf = np.zeros((0, QB, index.dim), np.float32)  # persistent
         self.upload_stats = {"full": 0, "delta": 0, "delta_slots": 0}
+        self.scan_stats = {"device_items": 0, "host_items": 0,
+                           "device_rows": 0, "host_rows": 0}
+        self.trace = TraceRecorder()  # wall channel off until switched
 
     # ----------------------------------------------------------- shard mode
     def enable_sharding(self, shard_owner, num_owners: int) -> None:
@@ -129,18 +126,23 @@ class HybridRetrievalEngine:
     def _device_arrays(self):
         """jnp mirror of the slab, maintained by per-slot delta uploads."""
         jnp = self._jnp
+        tr = self.trace
         if self._device_slab is None:
-            self._device_slab = (
-                jnp.asarray(self._slab),
-                jnp.asarray(self._slab_valid),
-            )
+            with (tr.span("ret.upload", slots=self.cache_capacity)
+                  if tr.wall else NOSPAN):
+                self._device_slab = (
+                    jnp.asarray(self._slab),
+                    jnp.asarray(self._slab_valid),
+                )
             self.upload_stats["full"] += 1
             self._dirty_slots.clear()
         elif self._dirty_slots:
             slots = np.fromiter(sorted(self._dirty_slots), np.int64)
-            ds, dv = self._device_slab
-            ds = ds.at[slots].set(jnp.asarray(self._slab[slots]))
-            dv = dv.at[slots].set(jnp.asarray(self._slab_valid[slots]))
+            with (tr.span("ret.upload", slots=int(slots.size))
+                  if tr.wall else NOSPAN):
+                ds, dv = self._device_slab
+                ds = ds.at[slots].set(jnp.asarray(self._slab[slots]))
+                dv = dv.at[slots].set(jnp.asarray(self._slab_valid[slots]))
             self._device_slab = (ds, dv)
             self.upload_stats["delta"] += 1
             self.upload_stats["delta_slots"] += int(slots.size)
@@ -153,7 +155,6 @@ class HybridRetrievalEngine:
         plan: RetrievalPlan,
         *,
         resident: Optional[np.ndarray] = None,
-        timing: Optional[SubstageTiming] = None,
         owner: Optional[int] = None,
     ) -> BatchTopK:
         """Execute one plan: device path for resident-cluster segments, host
@@ -172,68 +173,71 @@ class HybridRetrievalEngine:
         worker's slab takes this worker's host path.
         """
         out = BatchTopK.empty(plan.n_items, plan.k)
-        # records accesses; hit/miss stats and live residency are both
-        # owner-filtered in shard mode, matching the executed partition
-        cur = self.cache.lookup_batch(plan.cluster_ids, owner=owner)
-        if resident is None:
-            # per-segment residency from the per-item lookup (items of a
-            # segment share one cluster, so its first is representative)
-            seg_dev = cur[plan.seg_order[plan.seg_bounds[:-1]]]
-        else:
-            seg_dev = resident[plan.seg_cluster]
-        host_segs: list[int] = []
-        dev_segs: list[int] = []
-        dev_slots: dict[int, int] = {}
-        for s in range(plan.n_segments):
-            if not seg_dev[s]:
-                host_segs.append(s)
-                continue
-            cid = int(plan.seg_cluster[s])
-            if owner is None:
-                slot = self.cache._resident.get(cid)
+        tr = self.trace
+        with (tr.span("ret.partition", items=plan.n_items,
+                      segments=plan.n_segments)
+              if tr.wall else NOSPAN):
+            # records accesses; hit/miss stats and live residency are both
+            # owner-filtered in shard mode, matching the executed partition
+            cur = self.cache.lookup_batch(plan.cluster_ids, owner=owner)
+            if resident is None:
+                # per-segment residency from the per-item lookup (items of a
+                # segment share one cluster, so its first is representative)
+                seg_dev = cur[plan.seg_order[plan.seg_bounds[:-1]]]
             else:
-                slot = self.cache.slot_on_owner(cid, owner)
-            if slot is None or self._slot_cid[slot] != cid:
-                # swapped out between dispatch and execution
-                self.cache.stats.stale_fallbacks += int(
-                    plan.segment_rows(s).size)
-                host_segs.append(s)
-            else:
-                dev_segs.append(s)
-                dev_slots[s] = int(slot)
-
+                seg_dev = resident[plan.seg_cluster]
+            host_segs: list[int] = []
+            dev_segs: list[int] = []
+            dev_slots: dict[int, int] = {}
+            for s in range(plan.n_segments):
+                if not seg_dev[s]:
+                    host_segs.append(s)
+                    continue
+                cid = int(plan.seg_cluster[s])
+                if owner is None:
+                    slot = self.cache._resident.get(cid)
+                else:
+                    slot = self.cache.slot_on_owner(cid, owner)
+                if slot is None or self._slot_cid[slot] != cid:
+                    # swapped out between dispatch and execution
+                    self.cache.stats.stale_fallbacks += int(
+                        plan.segment_rows(s).size)
+                    host_segs.append(s)
+                else:
+                    dev_segs.append(s)
+                    dev_slots[s] = int(slot)
+        items = plan.seg_counts()
+        rows = self._sizes[plan.seg_cluster] * items
+        st = self.scan_stats
         if dev_segs:
-            t0 = time.perf_counter()
-            n_dev = self._device_scan(plan, dev_segs, out, dev_slots)
-            if timing is not None:
-                timing.device_us = (time.perf_counter() - t0) * 1e6
-                timing.n_device_items = n_dev
+            self._device_scan(plan, dev_segs, out, dev_slots)
+            st["device_items"] += int(items[dev_segs].sum())
+            st["device_rows"] += int(rows[dev_segs].sum())
         if host_segs:
-            t0 = time.perf_counter()
-            self.index.scan_segments(plan, np.asarray(host_segs, np.int64), out)
-            if timing is not None:
-                timing.host_us = (time.perf_counter() - t0) * 1e6
-                timing.n_host_items = int(
-                    sum(plan.segment_rows(s).size for s in host_segs))
+            host = np.asarray(host_segs, np.int64)
+            n_items, n_rows = int(items[host].sum()), int(rows[host].sum())
+            with (tr.span("ret.host_scan", items=n_items, rows=n_rows)
+                  if tr.wall else NOSPAN):
+                self.index.scan_segments(plan, host, out)
+            st["host_items"] += n_items
+            st["host_rows"] += n_rows
+        if tr.wall:
+            tr.count("ret.scanned", **st)
 
         self.cache.end_substage()
         return out
 
     def search_substage(
         self, work: Sequence[tuple[np.ndarray, int, TopK]]
-    ) -> tuple[list[TopK], SubstageTiming]:
+    ) -> list[TopK]:
         """Legacy per-item API: adapt the work list to a plan and execute."""
-        timing = SubstageTiming()
         if not work:
             self.cache.end_substage()  # empty sub-stages still tick the clock
-            return [], timing
+            return []
         plan = plan_from_work(work)
-        res = plan.finalize(self.search_plan(plan, timing=timing))
-        return (
-            [res.group_topk(g, int(plan.group_k[g]))
-             for g in range(plan.n_groups)],
-            timing,
-        )
+        res = plan.finalize(self.search_plan(plan))
+        return [res.group_topk(g, int(plan.group_k[g]))
+                for g in range(plan.n_groups)]
 
     # ------------------------------------------------------------ device path
     def _query_groups(self, n: int) -> np.ndarray:
@@ -244,7 +248,7 @@ class HybridRetrievalEngine:
         return self._qbuf
 
     def _device_scan(self, plan: RetrievalPlan, dev_segs, out: BatchTopK,
-                     dev_slots: Optional[dict] = None) -> int:
+                     dev_slots: Optional[dict] = None) -> None:
         """Pack resident segments into (G, QB, d) groups + fused scan, then
         one vectorized scatter-merge of all member rows.  ``dev_slots``
         (shard mode) carries the per-segment slot resolved on the executing
@@ -252,42 +256,51 @@ class HybridRetrievalEngine:
         from repro.kernels.ivf_scan import ivf_scan
 
         jnp = self._jnp
-        slab, valid = self._device_arrays()
-        k = min(plan.k, self.tile_len)
-        g_slots: list[int] = []
-        g_rows: list[np.ndarray] = []
-        for s in dev_segs:
-            if dev_slots is not None and s in dev_slots:
-                slot = dev_slots[s]
-            else:
-                slot = int(self.cache.slot_of(int(plan.seg_cluster[s])))
-            rows = plan.segment_rows(s)
-            for ofs in range(0, rows.size, QB):
-                g_slots.append(slot)
-                g_rows.append(rows[ofs: ofs + QB])
-        G = len(g_slots)
-        qbuf = self._query_groups(G)
-        qbuf[:G] = 0.0
-        for g, rows in enumerate(g_rows):
-            qbuf[g, : rows.size] = plan.queries[rows]
-        slots_arr = np.asarray(g_slots, np.int32)
-        dists, idx = ivf_scan(
-            jnp.asarray(qbuf[:G]), jnp.asarray(slots_arr), slab, valid, k,
-            impl=self.kernel_impl)
-        dists = np.asarray(dists)  # (G, QB, k)
-        idx = np.asarray(idx)
-        # local row -> doc id for all groups at once
-        sid = self._slab_ids[slots_arr]  # (G, L)
-        ids = np.take_along_axis(
-            sid, np.maximum(idx, 0).reshape(G, -1), axis=1).reshape(idx.shape)
-        ids = np.where(idx >= 0, ids, -1)
-        # one scatter-merge over the real (non-padded) member rows
-        counts = [r.size for r in g_rows]
-        rows_flat = np.concatenate(g_rows)
-        sel_g = np.repeat(np.arange(G), counts)
-        sel_r = np.concatenate([np.arange(c) for c in counts])
-        out.merge_rows(rows_flat, dists[sel_g, sel_r], ids[sel_g, sel_r])
-        return int(rows_flat.size)
+        tr = self.trace
+        with (tr.span("ret.device_scan") if tr.wall else NOSPAN) as span:
+            slab, valid = self._device_arrays()
+            k = min(plan.k, self.tile_len)
+            with (tr.span("ret.scan.pack") if tr.wall else NOSPAN):
+                g_slots: list[int] = []
+                g_rows: list[np.ndarray] = []
+                for s in dev_segs:
+                    if dev_slots is not None and s in dev_slots:
+                        slot = dev_slots[s]
+                    else:
+                        slot = int(self.cache.slot_of(int(plan.seg_cluster[s])))
+                    rows = plan.segment_rows(s)
+                    for ofs in range(0, rows.size, QB):
+                        g_slots.append(slot)
+                        g_rows.append(rows[ofs: ofs + QB])
+                G = len(g_slots)
+                qbuf = self._query_groups(G)
+                qbuf[:G] = 0.0
+                for g, rows in enumerate(g_rows):
+                    qbuf[g, : rows.size] = plan.queries[rows]
+                slots_arr = np.asarray(g_slots, np.int32)
+                dists, idx = ivf_scan(
+                    jnp.asarray(qbuf[:G]), jnp.asarray(slots_arr), slab, valid,
+                    k, impl=self.kernel_impl)
+            with (tr.span("ret.scan.wait") if tr.wall else NOSPAN):
+                dists = np.asarray(dists)  # (G, QB, k)
+                idx = np.asarray(idx)
+            with (tr.span("ret.scan.merge") if tr.wall else NOSPAN):
+                # local row -> doc id for all groups at once
+                sid = self._slab_ids[slots_arr]  # (G, L)
+                ids = np.take_along_axis(
+                    sid, np.maximum(idx, 0).reshape(G, -1),
+                    axis=1).reshape(idx.shape)
+                ids = np.where(idx >= 0, ids, -1)
+                # one scatter-merge over the real (non-padded) member rows
+                counts = [r.size for r in g_rows]
+                rows_flat = np.concatenate(g_rows)
+                sel_g = np.repeat(np.arange(G), counts)
+                sel_r = np.concatenate([np.arange(c) for c in counts])
+                out.merge_rows(rows_flat, dists[sel_g, sel_r],
+                               ids[sel_g, sel_r])
+            if span is not NOSPAN:
+                span.args.update(
+                    G=G, k=k, rows=int(self._slab_valid[slots_arr].sum()))
 
     # ---------------------------------------------------------------- stats
     def resident_mask(self, owner: Optional[int] = None) -> np.ndarray:
@@ -314,6 +327,7 @@ class HybridRetrievalEngine:
             "replica_loads": self.cache.stats.replica_loads,
             "replicated_clusters": len(self.cache.replicated_ids),
             "uploads": dict(self.upload_stats),
+            **self.scan_stats,
             "skew": self.cache.tracker.skewness_report(),
         }
 

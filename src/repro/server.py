@@ -270,16 +270,33 @@ class Server:
         return self.sched.crossreq.report()
 
     # -------------------------------------------------------- observability
+    def wall_trace(self, on: bool = True):
+        """Switch the wall-clock channel of the server's recorder
+        (``repro.obs.trace``) and hand that recorder to the backend and its
+        engines, so the ingress loop, the scheduler, the generation engine
+        and the retrieval engine all record into it.  Scheduling is
+        unchanged either way.  Returns the recorder."""
+        rec = self.sched.trace
+        for part in (self.backend, getattr(self.backend, "gen_engine", None),
+                     getattr(self.backend, "hybrid", None)):
+            if part is not None:
+                part.trace = rec
+        rec.set_wall(on)
+        return rec
+
     def export_trace(self, path: Optional[str] = None) -> dict:
-        """Chrome trace-event / Perfetto JSON of the run so far (requires
-        ``tracing=True``).  Returns the trace object; with ``path`` also
+        """Chrome trace-event / Perfetto JSON of the run so far: the
+        virtual-clock tracks (``tracing=True``) and the wall-clock channel's
+        (``wall_trace()``).  Returns the trace object; with ``path`` also
         writes it to disk (open in https://ui.perfetto.dev or
         ``chrome://tracing``)."""
-        if self.sched.obs is None:
+        rec = self.sched.trace
+        if self.sched.obs is None and not (rec.wall or rec.wall_spans):
             raise RuntimeError(
                 "tracing is off — construct the Server with tracing=True "
-                "(SchedulerConfig.tracing) to record spans")
-        trace = self.sched.obs.to_chrome()
+                "(SchedulerConfig.tracing) or call wall_trace() to record "
+                "spans")
+        trace = rec.to_chrome()
         if path:
             with open(path, "w") as f:
                 json.dump(trace, f, indent=1)

@@ -12,6 +12,13 @@ This engine is what RealBackend binds to (launch/serve.py).  Its jitted
 programs are module-level and keyed on the config, so engines built for the
 same model share compiled code, and ``warmup`` compiles every program a
 serving pass can reach before the pass is timed.
+
+With its recorder's wall channel on (``trace``, see ``repro.obs.trace``) a
+prefill records ``engine.prefill`` (tokens kept, padded width) with its
+dispatch, cache insert, first-token pull and last-token update as
+children, and a decode step ``engine.decode`` (live sequences, slots,
+total context) with its dispatch, the pull that waits for the new tokens,
+and the host bookkeeping.
 """
 from __future__ import annotations
 
@@ -25,6 +32,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models import lm
+from repro.obs.trace import NOSPAN, TraceRecorder
 from repro.serving.sampler import SamplerConfig, sample
 
 
@@ -47,7 +55,8 @@ def _bucket(n: int, buckets=(32, 64, 128, 256, 512, 1024, 2048)) -> int:
 
 def _decode_impl(params, state, tokens, key, active, *, cfg, sampler):
     logits, state = lm.decode_step(params, cfg, tokens, state)
-    nxt = sample(logits, key, sampler)
+    with jax.named_scope("sample"):
+        nxt = sample(logits, key, sampler)
     # frozen slots keep emitting pad; their cache_len must not grow
     state["cache_len"] = jnp.where(active, state["cache_len"],
                                    state["cache_len"] - 1)
@@ -61,7 +70,8 @@ def _insert_impl(slab_state, one_state, slot):
         # (L, B, ...) vs (L, 1, ...)
         return jax.lax.dynamic_update_slice_in_dim(slab, one.astype(slab.dtype), slot, axis=1)
 
-    return jax.tree.map(ins, slab_state, one_state)
+    with jax.named_scope("kv_write"):
+        return jax.tree.map(ins, slab_state, one_state)
 
 
 # (params, cfg, tokens (B, S), *, max_len) -> (last-token logits (B, V), state)
@@ -84,6 +94,7 @@ class GenerationEngine:
         self.state = lm.init_decode_state(cfg, max_batch, max_len)
         self.seqs: dict[int, Sequence] = {}
         self._key = jax.random.PRNGKey(seed)
+        self.trace = TraceRecorder()  # wall channel off until switched
         self._clear_slots()
 
     def _clear_slots(self) -> None:
@@ -131,56 +142,75 @@ class GenerationEngine:
         """Prefill a prompt into a free slot; returns seq id."""
         if not self.free_slots:
             raise RuntimeError("no free slots")
-        slot = self.free_slots.pop()
-        # keep the prompt suffix (left-pad semantics), and shrink the
-        # effective max_new to the headroom left after padding
-        keep = self._prompt_room(max_new)
-        prompt_tokens = np.asarray(prompt_tokens)
-        if len(prompt_tokens) > keep:
-            prompt_tokens = prompt_tokens[-keep:]
-        n = len(prompt_tokens)
-        pad_to = min(_bucket(n), keep)
-        max_new = min(max_new, self.max_len - pad_to)
-        toks = np.zeros((1, pad_to), np.int32)
-        toks[0, pad_to - n:] = prompt_tokens  # left-pad (simplest causal-safe)
-        logits, st1 = jit_prefill(self.params, self.cfg, jnp.asarray(toks),
-                                  max_len=self.max_len)
-        self.state = _insert(self.state, st1, slot)
-        # note: left-padding slightly pollutes the prefix; acceptable for the
-        # toy-model integration path (real deployment uses paged prefill)
-        first = int(jnp.argmax(logits[0]))
-        sid = self._next_id
-        self._next_id += 1
-        self.seqs[sid] = Sequence(sid, slot, n, max_new, [first])
-        self._active[slot] = True
-        lt = np.array(self._last_tokens)
-        lt[slot] = first
-        self._last_tokens = jnp.asarray(lt)
+        tr = self.trace
+        with (tr.span("engine.prefill") if tr.wall else NOSPAN) as span:
+            slot = self.free_slots.pop()
+            # keep the prompt suffix (left-pad semantics), and shrink the
+            # effective max_new to the headroom left after padding
+            keep = self._prompt_room(max_new)
+            prompt_tokens = np.asarray(prompt_tokens)
+            if len(prompt_tokens) > keep:
+                prompt_tokens = prompt_tokens[-keep:]
+            n = len(prompt_tokens)
+            pad_to = min(_bucket(n), keep)
+            max_new = min(max_new, self.max_len - pad_to)
+            toks = np.zeros((1, pad_to), np.int32)
+            toks[0, pad_to - n:] = prompt_tokens  # left-pad (simplest causal-safe)
+            with (tr.span("engine.prefill.dispatch") if tr.wall else NOSPAN):
+                logits, st1 = jit_prefill(self.params, self.cfg,
+                                          jnp.asarray(toks),
+                                          max_len=self.max_len)
+            with (tr.span("engine.insert") if tr.wall else NOSPAN):
+                self.state = _insert(self.state, st1, slot)
+            # note: left-padding slightly pollutes the prefix; acceptable for
+            # the toy-model integration path (real deployment uses paged
+            # prefill)
+            with (tr.span("engine.first_token") if tr.wall else NOSPAN):
+                first = int(jnp.argmax(logits[0]))
+            sid = self._next_id
+            self._next_id += 1
+            self.seqs[sid] = Sequence(sid, slot, n, max_new, [first])
+            self._active[slot] = True
+            with (tr.span("engine.last_tokens") if tr.wall else NOSPAN):
+                lt = np.array(self._last_tokens)
+                lt[slot] = first
+                self._last_tokens = jnp.asarray(lt)
+            if span is not NOSPAN:
+                span.args.update(tokens=n, width=pad_to)
         return sid
 
     def step(self) -> dict[int, int]:
         """One decode step over the slab; returns {seq_id: new_token}."""
         if not self.seqs:
             return {}
-        self._key, sub = jax.random.split(self._key)
-        active = jnp.asarray(self._active)
-        nxt, self.state = _decode(self.params, self.state, self._last_tokens,
-                                  sub, active, cfg=self.cfg,
-                                  sampler=self.sampler)
-        self._last_tokens = nxt
-        out: dict[int, int] = {}
-        nxt_np = np.asarray(nxt)
-        for sid, seq in list(self.seqs.items()):
-            if seq.done:
-                continue
-            tok = int(nxt_np[seq.slot])
-            seq.tokens.append(tok)
-            out[sid] = tok
-            if tok == self.eos_id or len(seq.tokens) >= seq.max_new:
-                seq.done = True
-                self._active[seq.slot] = False
-                self.free_slots.append(seq.slot)
-                del self.seqs[sid]
+        tr = self.trace
+        with (tr.span("engine.decode", live=len(self.seqs),
+                      slots=self.max_batch,
+                      ctx=sum(s.prompt_len + len(s.tokens) - 1
+                              for s in self.seqs.values()))
+              if tr.wall else NOSPAN):
+            with (tr.span("engine.decode.dispatch") if tr.wall else NOSPAN):
+                self._key, sub = jax.random.split(self._key)
+                active = jnp.asarray(self._active)
+                nxt, self.state = _decode(self.params, self.state,
+                                          self._last_tokens, sub, active,
+                                          cfg=self.cfg, sampler=self.sampler)
+                self._last_tokens = nxt
+            with (tr.span("engine.decode.pull") if tr.wall else NOSPAN):
+                nxt_np = np.asarray(nxt)
+            with (tr.span("engine.decode.book") if tr.wall else NOSPAN):
+                out: dict[int, int] = {}
+                for sid, seq in list(self.seqs.items()):
+                    if seq.done:
+                        continue
+                    tok = int(nxt_np[seq.slot])
+                    seq.tokens.append(tok)
+                    out[sid] = tok
+                    if tok == self.eos_id or len(seq.tokens) >= seq.max_new:
+                        seq.done = True
+                        self._active[seq.slot] = False
+                        self.free_slots.append(seq.slot)
+                        del self.seqs[sid]
         return out
 
     def step_batch(self, n_steps: int) -> None:

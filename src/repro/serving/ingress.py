@@ -37,7 +37,16 @@ trace rows, so they replay exactly.
 
 This module is the *only* place in the serving packages allowed to read
 the wall clock (``repro-lint`` policy ``wallclock_ingress_paths``); obs
-taps receive wall values as arguments and never read time themselves.
+taps receive wall values as arguments, and the recorder's wall channel
+reads its clock only at its one sanctioned site in ``obs/trace.py``.
+
+With the server recorder's wall channel on (``Server.wall_trace``) each
+arrival is stamped on the recorder's clock as it enters the queue; the loop
+records ``serve.queue`` (from that stamp to submission) and ``serve.done``
+(at ticket resolution) under the request's id, and the scheduler
+``sched.admit`` when it takes the request in, so a request's wall latency
+splits into ingress wait, service and the wait between
+(``obs.attribution.wall_breakdown``).
 """
 from __future__ import annotations
 
@@ -102,6 +111,7 @@ class IngressItem:
     text: str = ""
     wid: int = -1
     ticket: Optional["Ticket"] = None
+    stamp_ns: int = -1  # the recorder's clock at ``put`` (wall channel on)
 
 
 @owned_by("ingress")
@@ -111,8 +121,9 @@ class IngressQueue:
     ``drain`` swaps the whole batch out under the lock, so the scheduler
     thread holds it for O(1) list moves, never while scheduling."""
 
-    def __init__(self, maxsize: int = 4096):
+    def __init__(self, maxsize: int = 4096, recorder=None):
         self.maxsize = max(1, int(maxsize))
+        self.recorder = recorder  # stamps arrivals while its wall channel is on
         self._lock = threading.Lock()
         self._not_full = threading.Condition(self._lock)
         self._items: list[IngressItem] = []
@@ -126,6 +137,8 @@ class IngressQueue:
         """Producer side: enqueue a row, blocking while the queue is full.
         Returns the assigned submission sequence number, or ``None`` when
         the queue closed (or stayed full past ``timeout_s``)."""
+        rec = self.recorder
+        stamp = rec.stamp() if rec is not None and rec.wall else -1
         deadline = time.monotonic() + float(timeout_s)
         with self._not_full:
             while len(self._items) >= self.maxsize and not self._closed:
@@ -138,7 +151,7 @@ class IngressQueue:
             self._seq += 1
             self._items.append(IngressItem(
                 seq=seq, t_us=float(t_us), kind=kind, workflow=workflow,
-                text=text, wid=int(wid), ticket=ticket))
+                text=text, wid=int(wid), ticket=ticket, stamp_ns=stamp))
             return seq
 
     @handoff("server")
@@ -393,7 +406,9 @@ class ServingLoop:
                  poll_interval_s: float = 0.0005):
         self.server = server
         self.clock = clock if clock is not None else WallClock()
-        self.queue = IngressQueue(maxsize=queue_maxsize)
+        self.recorder = server.sched.trace
+        self.queue = IngressQueue(maxsize=queue_maxsize,
+                                  recorder=self.recorder)
         self.trace = trace if trace is not None else ArrivalTrace()
         self.tick_interval_us = float(tick_interval_us)
         self.readmit_enabled = bool(readmit)
@@ -436,6 +451,8 @@ class ServingLoop:
         eff = self._advance(it.t_us)
         req = self.server.build_request(it.text, it.workflow, eff)
         rid = self.server.submit_built(req)
+        if rid is not None and it.stamp_ns >= 0 and self.recorder.wall:
+            self.recorder.mark("serve.queue", t0_ns=it.stamp_ns, rid=rid)
         self.trace.record(TraceRow(
             seq=it.seq, t_us=eff, kind=ARRIVAL, workflow=it.workflow,
             text=it.text, admitted=rid is not None,
@@ -477,6 +494,8 @@ class ServingLoop:
                 t.resolve("finished", request_id=r.request_id,
                           finish_us=r.finish_us,
                           latency_us=float(r.finish_us) - float(r.arrival_us))
+            if self.recorder.wall:
+                self.recorder.mark("serve.done", rid=r.request_id)
 
     def _maybe_readmit(self) -> None:
         if not self._parked:
