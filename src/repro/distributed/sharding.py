@@ -248,19 +248,24 @@ def decode_state_spec(cfg: ModelConfig, mesh: Mesh, batch: int, path, leaf,
     def mod_ax(dim: int):
         return tp if (tp and dim % _axis_size(mesh, tp) == 0) else None
 
-    if name in ("k", "v"):  # (L, B, S, KV, dh)
+    if name in ("k", "v") and "enc_kv" in names:  # (L, B, Se, KV, dh)
         sax = seq_axes(leaf.shape[2])
         if sax is None and tp and leaf.shape[3] % _axis_size(mesh, tp) == 0:
             # sequence not shardable (e.g. enc-dec cross KV, 1500 frames):
             # shard heads instead so per-step reshards disappear
             return P(None, bax, None, tp, None)
         return P(None, bax, sax, None, None)
-    if name in ("k_scale", "v_scale"):  # (L, B, S, KV) int8-cache scales
-        return P(None, bax, seq_axes(leaf.shape[2]), None)
+    if name in ("k", "v"):  # heads-major self-attention cache (L, B, KV, S, dh)
+        sax = seq_axes(leaf.shape[3])
+        if sax is None and tp and leaf.shape[2] % _axis_size(mesh, tp) == 0:
+            return P(None, bax, tp, None, None)
+        return P(None, bax, None, sax, None)
+    if name in ("k_scale", "v_scale"):  # (L, B, KV, S) int8-cache scales
+        return P(None, bax, None, seq_axes(leaf.shape[3]))
     if name == "ckv":  # (L, B, S, r)
         return P(None, bax, seq_axes(leaf.shape[2]), None)
-    if name == "kpe":  # (L, B, S, rope_dim)
-        return P(None, bax, seq_axes(leaf.shape[2]), None)
+    if name == "kpe":  # (L, B, rope_dim, S)
+        return P(None, bax, None, seq_axes(leaf.shape[3]))
     if name == "S":  # rwkv state (L, B, H, N, N)
         return P(None, bax, mod_ax(leaf.shape[2]), None, None)
     if name == "x_prev":  # (L, B, 1, d)

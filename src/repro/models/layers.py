@@ -242,21 +242,25 @@ def _qkv(cfg: ModelConfig, p: Params, x: jax.Array, positions):
 
 
 def attention_init_state(cfg: ModelConfig, seg: Segment, batch: int, max_len: int):
-    """Decode-state skeleton (zeros) for one attention layer."""
+    """Decode-state skeleton (zeros) for one attention layer.
+
+    The cache is heads-major, (B, KV, T, dh): the layout decode attention's
+    contraction reads, so a step reads each layer's cache as it lies.
+    """
     dt = dtype_of(cfg)
     KV, dh = cfg.n_kv_heads, cfg.d_head
     if seg.mixer == "local_attn":
         max_len = min(max_len, cfg.local_window)
     if cfg.kv_cache_dtype == "int8":
         return {
-            "k": jnp.zeros((batch, max_len, KV, dh), jnp.int8),
-            "v": jnp.zeros((batch, max_len, KV, dh), jnp.int8),
-            "k_scale": jnp.zeros((batch, max_len, KV), f32),
-            "v_scale": jnp.zeros((batch, max_len, KV), f32),
+            "k": jnp.zeros((batch, KV, max_len, dh), jnp.int8),
+            "v": jnp.zeros((batch, KV, max_len, dh), jnp.int8),
+            "k_scale": jnp.zeros((batch, KV, max_len), f32),
+            "v_scale": jnp.zeros((batch, KV, max_len), f32),
         }
     return {
-        "k": jnp.zeros((batch, max_len, KV, dh), dt),
-        "v": jnp.zeros((batch, max_len, KV, dh), dt),
+        "k": jnp.zeros((batch, KV, max_len, dh), dt),
+        "v": jnp.zeros((batch, KV, max_len, dh), dt),
     }
 
 
@@ -283,8 +287,10 @@ def apply_attention(
     state: Optional[Params] = None,
     cache_len: Optional[jax.Array] = None,
     max_len: int = 0,
+    layer=None,
 ):
-    """Returns (out, new_state)."""
+    """Returns (out, new_state).  In decode, ``state`` is the segment's
+    stacked cache and ``layer`` this layer's index in it."""
     B, S, _ = x.shape
     H, dh = cfg.n_heads, cfg.d_head
     window = cfg.local_window if seg.mixer == "local_attn" else 0
@@ -301,17 +307,18 @@ def apply_attention(
     if mode == "prefill":
         out = blocked_attention(q, k, v, causal=causal, window=window, q_chunk=cfg.attn_q_chunk)
         out = constrain(out, "dp", None, "tp", None)
+        kt, vt = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)  # heads-major
         if window:
             # keep only the trailing window in the ring cache
             pad = max(0, window - S)
-            kw = jnp.pad(k[:, -window:], ((0, 0), (pad, 0), (0, 0), (0, 0)))
-            vw = jnp.pad(v[:, -window:], ((0, 0), (pad, 0), (0, 0), (0, 0)))
+            kw = jnp.pad(kt[:, :, -window:], ((0, 0), (0, 0), (pad, 0), (0, 0)))
+            vw = jnp.pad(vt[:, :, -window:], ((0, 0), (0, 0), (pad, 0), (0, 0)))
             st = {"k": kw.astype(k.dtype), "v": vw.astype(v.dtype)}
         else:
             pad = max_len - S
             st = {
-                "k": jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0))),
-                "v": jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0))),
+                "k": jnp.pad(kt, ((0, 0), (0, 0), (0, pad), (0, 0))),
+                "v": jnp.pad(vt, ((0, 0), (0, 0), (0, pad), (0, 0))),
             }
         if int8_kv:
             kq, ks = _quantize_kv(st["k"])
@@ -319,53 +326,47 @@ def apply_attention(
             st = {"k": kq, "k_scale": ks, "v": vq, "v_scale": vs}
         return out.reshape(B, S, H * dh) @ p["wo"], st
 
-    # decode: S == 1
-    assert state is not None and cache_len is not None
+    # decode: S == 1.  ``state`` is the segment's stacked cache
+    # (L, B, KV, T, ...) and ``layer`` this layer's index in it: the new row
+    # goes in place at [layer, b, :, slot[b]], then attention reads the
+    # layer's slice.
+    assert state is not None and cache_len is not None and layer is not None
+    slot = (cache_len % window) if window else cache_len  # ring or append
+    # a full ring holds exactly the last ``window`` positions
+    eff_len = jnp.minimum(cache_len + 1, window) if window else cache_len + 1
     if int8_kv:
         kq, ks = _quantize_kv(k)
         vq, vs = _quantize_kv(v)
-        slot = (cache_len % window) if window else cache_len
-        st = {
-            "k": _scatter_time(state["k"], kq, slot),
-            "k_scale": _scatter_time(state["k_scale"], ks, slot),
-            "v": _scatter_time(state["v"], vq, slot),
-            "v_scale": _scatter_time(state["v_scale"], vs, slot),
-        }
-        k_full = _dequantize_kv(st["k"], st["k_scale"], k.dtype)
-        v_full = _dequantize_kv(st["v"], st["v_scale"], v.dtype)
-        eff_len = jnp.minimum(cache_len + 1, window) if window else cache_len + 1
-        out = decode_attention_ref(q, k_full, v_full, eff_len)
-        return out.reshape(B, S, H * dh) @ p["wo"], st
-    if window:
-        # ring buffer: write slot = cache_len % window
-        slot = cache_len % window
-        k_new = _scatter_time(state["k"], k, slot)
-        v_new = _scatter_time(state["v"], v, slot)
-        eff_len = jnp.minimum(cache_len + 1, window)
-        # positions for masking inside ring: all entries valid up to eff_len
-        out = decode_attention_ref(q, k_new, v_new, eff_len)
-        st = {"k": k_new, "v": v_new}
+        new = {"k": kq, "k_scale": ks, "v": vq, "v_scale": vs}
     else:
-        # dynamic per-batch write at cache_len
-        k_new = _scatter_time(state["k"], k, cache_len)
-        v_new = _scatter_time(state["v"], v, cache_len)
-        out = decode_attention_ref(q, k_new, v_new, cache_len + 1)
-        st = {"k": k_new, "v": v_new}
+        new = {"k": k, "v": v}
+    # one (dh,) row per sequence and head: the stack keeps its heads-major
+    # layout through the scatter
+    at = (layer, jnp.arange(B)[:, None], jnp.arange(cfg.n_kv_heads)[None, :],
+          slot[:, None])
+    st = {n: _write_rows(state[n], at, r[:, 0]) for n, r in new.items()}
+    cur = {n: lax.dynamic_index_in_dim(a, layer, keepdims=False)
+           for n, a in st.items()}
+    if int8_kv:
+        k_full = _dequantize_kv(cur["k"], cur["k_scale"], k.dtype)
+        v_full = _dequantize_kv(cur["v"], cur["v_scale"], v.dtype)
+    else:
+        k_full, v_full = cur["k"], cur["v"]
+    out = decode_attention_ref(q, jnp.swapaxes(k_full, 1, 2),
+                               jnp.swapaxes(v_full, 1, 2), eff_len)
     return out.reshape(B, S, H * dh) @ p["wo"], st
 
 
-def _scatter_time(cache: jax.Array, new: jax.Array, lengths: jax.Array) -> jax.Array:
-    """Write new (B, 1, ...) at per-sequence time position lengths (B,).
+def _write_rows(stack: jax.Array, at: tuple, rows: jax.Array) -> jax.Array:
+    """Write one new row per sequence into a stacked cache: ``at`` indexes
+    the layer, the sequences and each one's position.
 
-    vmap of dynamic_update_slice keeps memory traffic at O(slice), not
-    O(cache) — with buffer donation this is an in-place cache update.
+    One scatter into the stack: carried through the decode layer loop and
+    donated by the caller, it updates the cache in place.
     """
-
-    def upd(c, n, start):
-        return lax.dynamic_update_slice_in_dim(c, n.astype(c.dtype), start, axis=0)
-
     with jax.named_scope("kv_write"):
-        return jax.vmap(upd)(cache, new, lengths)
+        return stack.at[at].set(rows.astype(stack.dtype),
+                                indices_are_sorted=True, unique_indices=True)
 
 
 # ---------------------------------------------------------------------------
@@ -429,10 +430,12 @@ def init_mla(cfg: ModelConfig, seg: Segment, key) -> Params:
 
 
 def mla_init_state(cfg: ModelConfig, batch: int, max_len: int):
+    """Latent cache: ``ckv`` (B, T, r) and ``kpe`` (B, rope_dim, T), the rope
+    part stored position-minor so decode's contraction reads it as it lies."""
     dt = dtype_of(cfg)
     return {
         "ckv": jnp.zeros((batch, max_len, cfg.kv_lora_rank), dt),
-        "kpe": jnp.zeros((batch, max_len, cfg.rope_head_dim), dt),
+        "kpe": jnp.zeros((batch, cfg.rope_head_dim, max_len), dt),
     }
 
 
@@ -470,6 +473,7 @@ def apply_mla(
     state=None,
     cache_len=None,
     max_len: int = 0,
+    layer=None,
 ):
     B, S, _ = x.shape
     H = cfg.n_heads
@@ -493,19 +497,25 @@ def apply_mla(
             pad = max_len - S
             st = {
                 "ckv": jnp.pad(ckv, ((0, 0), (0, pad), (0, 0))),
-                "kpe": jnp.pad(kpe, ((0, 0), (0, pad), (0, 0))),
+                "kpe": jnp.pad(jnp.swapaxes(kpe, 1, 2), ((0, 0), (0, 0), (0, pad))),
             }
         return y, st
 
     # decode: absorbed formulation — attention in latent space, no per-head
     # K/V materialisation.  scores = q_nope @ Wk_b^T(head) @ ckv + q_pe @ kpe
-    assert state is not None
-    ckv_c = _scatter_time(state["ckv"], ckv, cache_len)
-    kpe_c = _scatter_time(state["kpe"], kpe, cache_len)
+    # ``state`` is the segment's stacked latent cache, written in place at
+    # position cache_len[b] of [layer, b] as in apply_attention
+    assert state is not None and layer is not None
+    b = jnp.arange(B)[:, None]
+    st = {"ckv": _write_rows(state["ckv"], (layer, b, cache_len[:, None]), ckv),
+          "kpe": _write_rows(state["kpe"], (layer, b, jnp.arange(rp)[None, :],
+                                            cache_len[:, None]), kpe[:, 0])}
+    ckv_c, kpe_c = (lax.dynamic_index_in_dim(st[n], layer, keepdims=False)
+                    for n in ("ckv", "kpe"))
     wk_b = p["wk_b"].reshape(r, H, np_)
     q_lat = jnp.einsum("bshn,rhn->bshr", q_nope.astype(f32), wk_b.astype(f32))  # (B,1,H,r)
     scores = jnp.einsum("bshr,btr->bhst", q_lat, ckv_c.astype(f32))
-    scores += jnp.einsum("bshp,btp->bhst", q_pe.astype(f32), kpe_c.astype(f32))
+    scores += jnp.einsum("bshp,bpt->bhst", q_pe.astype(f32), kpe_c.astype(f32))
     scores *= 1.0 / math.sqrt(np_ + rp)
     Smax = ckv_c.shape[1]
     valid = jnp.arange(Smax)[None, :] < (cache_len + 1)[:, None]
@@ -515,7 +525,7 @@ def apply_mla(
     wv_b = p["wv_b"].reshape(r, H, vd)
     out = jnp.einsum("bshr,rhv->bshv", ctx, wv_b.astype(f32)).astype(x.dtype)
     y = out.reshape(B, S, H * vd) @ p["wo"]
-    return y, {"ckv": ckv_c, "kpe": kpe_c}
+    return y, st
 
 
 # ---------------------------------------------------------------------------

@@ -59,6 +59,11 @@ _MIXER_INIT = {
     "rglru": rglru.init_rglru,
 }
 
+# mixers whose decode state is a per-position cache.  The decode layer loop
+# carries their stacked cache and each layer writes only its new row into it;
+# the other mixers' small per-layer states stay the loop's xs and ys.
+_CACHE_MIXERS = frozenset({"attn", "local_attn", "mla"})
+
 _MIXER_APPLY = {
     "attn": apply_attention,
     "local_attn": apply_attention,
@@ -134,6 +139,7 @@ def _apply_block(
     cache_len,
     enc_out,
     max_len: int,
+    layer=None,
 ):
     st_in = state or {}
     h = apply_norm(cfg, p["norm1"], x)
@@ -141,7 +147,7 @@ def _apply_block(
         mix_out, mix_st = _MIXER_APPLY[seg.mixer](
             cfg, seg, p["mixer"], h,
             mode=mode, positions=positions, state=st_in.get("mixer"),
-            cache_len=cache_len, max_len=max_len,
+            cache_len=cache_len, max_len=max_len, layer=layer,
         )
     x = x + mix_out
 
@@ -231,26 +237,43 @@ def _run_segment(
             states = jax.tree.map(lambda *a: jnp.stack(a), *sts)
         return x, states
 
-    # decode
+    # decode.  A cache-keeping mixer gets its segment's whole stacked cache,
+    # carried through the layer loop, and writes its one new row into it in
+    # place; as scan xs/ys each layer's cache would be sliced out, written
+    # back and the stack copied whole every step.
+    in_place = seg.mixer in _CACHE_MIXERS
+    rest = dict(stacked_state)
+    cache = rest.pop("mixer") if in_place else None
+    rest = rest or None
+
     def body(carry, inp):
-        lp, st = inp
+        x, cache = carry
+        lp, i, st = inp
+        if in_place:
+            st = {**(st or {}), "mixer": cache}
         out, st2 = _apply_block(
-            cfg, seg, lp, carry, mode=mode, positions=positions,
+            cfg, seg, lp, x, mode=mode, positions=positions,
             state=st, cache_len=cache_len, enc_out=enc_out, max_len=max_len,
+            layer=i,
         )
-        return out, st2
+        if in_place:
+            st2 = dict(st2)
+            cache = st2.pop("mixer")
+        return (out, cache), (st2 or None)
 
     if cfg.scan_layers:
-        x, new_states = lax.scan(body, x, (stacked_p, stacked_state))
+        (x, cache), new_rest = lax.scan(
+            body, (x, cache), (stacked_p, jnp.arange(seg.repeat), rest))
     else:
         sts = []
         for i in range(seg.repeat):
-            lp = jax.tree.map(lambda a: a[i], stacked_p)
-            st = jax.tree.map(lambda a: a[i], stacked_state)
-            x, st2 = body(x, (lp, st))
+            lp, st = jax.tree.map(lambda a: a[i], (stacked_p, rest))
+            (x, cache), st2 = body((x, cache), (lp, i, st))
             sts.append(st2)
-        new_states = jax.tree.map(lambda *a: jnp.stack(a), *sts)
-    return x, new_states
+        new_rest = jax.tree.map(lambda *a: jnp.stack(a), *sts)
+    if in_place:
+        return x, {**(new_rest or {}), "mixer": cache}
+    return x, new_rest
 
 
 # ---------------------------------------------------------------------------

@@ -110,22 +110,60 @@ def test_decode_attention_compiles_for_v5e(one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_served_model_compiles_for_v5e(one_chip):
-    """The launcher's prefill and decode programs at qwen3-1.7b widths."""
+def _qwen3_programs(sharding, slots, max_len):
+    """qwen3-1.7b's config, parameter and decode-state shapes on a chip."""
     from repro.configs import get_config
     from repro.models import lm
-    from repro.serving.engine import _decode, jit_prefill
-    from repro.serving.sampler import SamplerConfig
 
     cfg = get_config("qwen3-1.7b")
     on_chip = lambda tree: jax.tree.map(
-        lambda a: _spec(one_chip, a.shape, a.dtype), tree)
+        lambda a: _spec(sharding, a.shape, a.dtype), tree)
     params = on_chip(jax.eval_shape(
         lambda: lm.init_params(cfg, jax.random.PRNGKey(0))))
-    state = on_chip(jax.eval_shape(lambda: lm.init_decode_state(cfg, 8, 160)))
+    state = on_chip(jax.eval_shape(
+        lambda: lm.init_decode_state(cfg, slots, max_len)))
+    return cfg, params, state
+
+
+def _lower_decode(sharding, cfg, params, state):
+    from repro.serving.engine import _decode
+    from repro.serving.sampler import SamplerConfig
+
+    B = state["cache_len"].shape[0]
+    return _decode.lower(params, state, _spec(sharding, (B,), jnp.int32),
+                         _spec(sharding, (2,), jnp.uint32),
+                         _spec(sharding, (B,), jnp.bool_),
+                         cfg=cfg, sampler=SamplerConfig())
+
+
+def test_served_model_compiles_for_v5e(one_chip):
+    """The launcher's prefill and decode programs at qwen3-1.7b widths."""
+    from repro.serving.engine import jit_prefill
+
+    cfg, params, state = _qwen3_programs(one_chip, 8, 160)
     jit_prefill.lower(params, cfg, _spec(one_chip, (1, 32), jnp.int32),
                       max_len=160).compile()
-    _decode.lower(params, state, _spec(one_chip, (8,), jnp.int32),
-                  _spec(one_chip, (2,), jnp.uint32),
-                  _spec(one_chip, (8,), jnp.bool_),
-                  cfg=cfg, sampler=SamplerConfig()).compile()
+    _lower_decode(one_chip, cfg, params, state).compile()
+
+
+def test_decode_updates_cache_in_place_for_v5e(one_chip):
+    """The served decode program (qwen3-1.7b, 16 slots x 2048 tokens) writes
+    each new KV row into the donated cache: its temporaries stay below one
+    layer's K cache, and nothing copies a whole stacked cache."""
+    import re
+
+    B, T = 16, 2048
+    cfg, params, state = _qwen3_programs(one_chip, B, T)
+    compiled = _lower_decode(one_chip, cfg, params, state).compile()
+    mem = compiled.memory_analysis()
+    layer_k = B * T * cfg.n_kv_heads * cfg.d_head * 2
+    assert mem.temp_size_in_bytes < layer_k, mem.temp_size_in_bytes
+    caches = jax.tree.leaves(state["segments"])
+    assert mem.alias_size_in_bytes >= sum(a.size * a.dtype.itemsize
+                                          for a in caches)
+    hlo_type = {"bfloat16": "bf16", "float32": "f32", "int8": "s8"}
+    whole = "|".join(
+        re.escape(f"{hlo_type[a.dtype.name]}[{','.join(map(str, a.shape))}]")
+        for a in caches)
+    copies = re.findall(rf"= (?:{whole})\S* copy\(", compiled.as_text())
+    assert not copies, copies
