@@ -3,7 +3,8 @@
 Three things are compared, on what the timed path produced:
 
 * generation: a sample of served sequences, drawn from the seed with the
-  longest in it.  The plain float32 reference (``bench.reference.lm``) runs
+  longest in it.  The plain float32 reference of the configuration's model
+  family (its ``score``, ``bench/families``) runs
   once over each sequence's model input with its served tokens, and the
   number compared is the widest gap by which a served token's logit lies
   below the reference's best logit at that position.  The model input is
@@ -108,19 +109,18 @@ def take_sample(st, ret_log, seed: int, config: dict, unanswered: int) -> Sample
                   skew=probe_skew(ret_log.rows, idx.n_clusters))
 
 
-def gen_gaps(sample: Sample, m: dict, seed: int, precision: str = "f32") -> dict:
+def gen_gaps(sample: Sample, family, m: dict, seed: int, precision: str = "f32") -> dict:
     """Widest gap of a served token's reference logit below the reference's
     best, over the sample; with ``precision="fp8"`` the gap of the token the
-    fp8 control puts first instead (the control's reading)."""
-    from bench.reference import lm
-
+    fp8 control puts first instead (the control's reading).  The reference
+    is the model family's ``score``."""
     seqs = [{"tokens": s["tokens"], "positions": s["positions"],
              "cands": s["served"][:, None]} for s in sample.gen]
     if precision != "f32":
-        ctrl = lm.score(m, seed, seqs, precision=precision)
+        ctrl = family.score(m, seed, seqs, precision=precision)
         for s, c in zip(seqs, ctrl):
             s["cands"] = c["argmax"][:, None]
-    ref = lm.score(m, seed, seqs)
+    ref = family.score(m, seed, seqs)
     gaps = np.concatenate([r["best"] - r["picked"][:, 0] for r in ref]) if ref else np.zeros(0)
     return {"max_gap": float(gaps.max()) if gaps.size else 0.0,
             "tokens": int(gaps.size), "sequences": len(seqs),
@@ -170,7 +170,7 @@ def ret_errors(sample: Sample, tol: float, control_dtype=None) -> dict:
     return {"max_dist_err": worst, "mismatched": mismatched, "stages": len(sample.ret)}
 
 
-def controls(sample: Sample, config: dict, seed: int) -> dict:
+def controls(sample: Sample, config: dict, family, seed: int) -> dict:
     """The controls' readings on the same sample: the reference computed a
     precision below the configuration's, in the program's place (fp8 for
     the bf16 model, bfloat16 for the float32 index)."""
@@ -178,18 +178,18 @@ def controls(sample: Sample, config: dict, seed: int) -> dict:
 
     from bench.stack import model_dict
 
-    g = gen_gaps(sample, model_dict(config), seed, precision="fp8")
+    g = gen_gaps(sample, family, model_dict(config), seed, precision="fp8")
     r = ret_errors(sample, float(config["limits"]["ret_dist_err"]),
                    control_dtype=ml_dtypes.bfloat16)
     return {"gen_logit_gap": g["max_gap"], "ret_dist_err": r["max_dist_err"],
             "ret_ids_mismatched": r["mismatched"]}
 
 
-def check(sample: Sample, config: dict, seed: int, accounting: int) -> list:
+def check(sample: Sample, config: dict, family, seed: int, accounting: int) -> list:
     from bench.stack import model_dict
 
     lim = config["limits"]
-    g = gen_gaps(sample, model_dict(config), seed)
+    g = gen_gaps(sample, family, model_dict(config), seed)
     r = ret_errors(sample, float(lim["ret_dist_err"]))
 
     def row(name, value, limit, detail):

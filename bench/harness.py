@@ -107,11 +107,11 @@ def run(root: Path, spec: dict, seed: int, seconds: float, trace_on: bool,
         t_start: float, *, devices=None, fault=None, with_controls=False) -> dict:
     import jax
 
-    config, t = spec["config"], spec["traffic"]
+    config, t, family = spec["config"], spec["traffic"], spec["family"]
     meter = CompileMeter()
     spans = Spans(annotate=trace_on)
     items = traffic.schedule(t, seed, seconds)
-    st = stack_mod.build(config, t, seed, spans, [i for _, _, i in items])
+    st = stack_mod.build(config, t, seed, spans, [i for _, _, i in items], family)
     scans = ScanRecorder()
     uploads: dict = {}  # slots staged -> uploads
     search = st.server.backend.search_charged
@@ -177,7 +177,7 @@ def run(root: Path, spec: dict, seed: int, seconds: float, trace_on: bool,
         "seconds": seconds, "window": (t_window0, t_window1), "spans": spans,
         "requests": reqs, "gen_counts": dict(st.gen.counts), "cache0": cache0,
         "cache1": cache1, "trace": prof.reduced,
-        "trace_window": prof.window, "model": stack_mod.model_dict(config),
+        "trace_window": prof.window, "model": stack_mod.model_dict(config), "family": family,
         "scan_rows": scans.rows(*prof.window) if prof.window else [],
         "device_kind": devs[0].device_kind, "config": config,
     }
@@ -189,8 +189,8 @@ def run(root: Path, spec: dict, seed: int, seconds: float, trace_on: bool,
     # never falls, and the references must not set it
     del st, lg, ret_log
     gc.collect()
-    checks = correct.check(sample, config, seed, accounting)
-    ctrl = correct.controls(sample, config, seed) if with_controls else None
+    checks = correct.check(sample, config, family, seed, accounting)
+    ctrl = correct.controls(sample, config, family, seed) if with_controls else None
     return {"ctx": ctx, "checks": checks, "controls": ctrl, "peak": peak,
             "in_window": in_window,
             "warm": warm_info, "scan_shapes": scans.shapes(t_window0),

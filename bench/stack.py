@@ -1,9 +1,10 @@
 """Build one cell's serving stack from its configuration and traffic files.
 
-Weights, corpus and index come from the seed; the stack is the program's
-own: ``GenerationEngine``, ``HybridRetrievalEngine``, ``RealBackend`` and a
-``Server`` in ``hedra`` mode, with the generation load adapter in place of
-the backend's generation entry.
+Weights, corpus and index come from the seed, the model's configuration and
+weights through the configuration's family (``bench/families``); the stack
+is the program's own: ``GenerationEngine``, ``HybridRetrievalEngine``,
+``RealBackend`` and a ``Server`` in ``hedra`` mode, with the generation load
+adapter in place of the backend's generation entry.
 """
 from __future__ import annotations
 
@@ -11,7 +12,7 @@ import dataclasses
 
 import numpy as np
 
-from bench import corpus, traffic, weights
+from bench import corpus, traffic
 from bench.adapters.gen_load import GenLoad
 
 
@@ -21,7 +22,8 @@ def model_dict(config: dict) -> dict:
 
 
 def model_config(config: dict):
-    """The program's ``ModelConfig`` at the file's widths."""
+    """The program's ``ModelConfig`` at the file's widths: a dense GQA +
+    SwiGLU stack (the dense family's ``program_config``)."""
     from repro.configs import get_config
     from repro.configs.base import Segment
 
@@ -51,7 +53,7 @@ class Stack:
     cfg: object
 
 
-def build(config: dict, t: dict, seed: int, spans, identities: list) -> Stack:
+def build(config: dict, t: dict, seed: int, spans, identities: list, family) -> Stack:
     from repro.core.backends import RealBackend
     from repro.retrieval import HybridRetrievalEngine
     from repro.server import Server
@@ -60,9 +62,9 @@ def build(config: dict, t: dict, seed: int, spans, identities: list) -> Stack:
     r, e = config["retrieval"], config["engine"]
     with spans.span("setup.corpus"):
         index, topics = corpus.build_index(r, seed)
-    cfg = model_config(config)
+    cfg = family.program_config(config)
     with spans.span("setup.weights"):
-        params = weights.program_params(model_dict(config), seed)
+        params = family.program_params(model_dict(config), seed)
     hybrid = HybridRetrievalEngine(index, cache_capacity=int(r["hot_clusters"]),
                                    tile_len=int(r["tile_len"]),
                                    update_interval=int(r["update_interval"]))

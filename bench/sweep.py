@@ -47,7 +47,7 @@ def main() -> int:
     devs = harness.require_chips(int(spec["cell"]["chips"]))
     harness.enable_cache()
     for rate in args.rates:
-        sp = copy.deepcopy(spec)
+        sp = dict(spec, traffic=copy.deepcopy(spec["traffic"]))
         sp["traffic"]["rate_per_s"] = rate
         sp["traffic"].pop("bursts", None)
         t0 = T_START

@@ -1,49 +1,72 @@
 """Configuration files: the widths are the published ones the program's own
-configs carry, nothing listed as reduced is a width, and the benchmark's
-weights have the program's parameter layout."""
+configs carry, nothing listed as reduced is a width, every file names a model
+family that loads, and the family's weights have the program's parameter
+layout.  Nothing here knows a family's keys: the program's configuration
+comes from the family."""
 import json
 from pathlib import Path
 
 import jax
 import pytest
 
-from bench import stack, weights
+from bench import manifest, stack, weights
 
 REPO = Path(__file__).resolve().parents[2]
 MANIFEST = json.loads((REPO / "BENCHMARK.json").read_text())
 CONFIGS = {c["name"]: c for c in MANIFEST["configs"]}
+# every configuration file, those no cell uses yet too, by name
+FILES = {p.stem: p for p in (REPO / "bench" / "configs").glob("*.json")}
 WIDTHS = ("hidden", "intermediate", "latent", "state", "proj", "head",
           "expansion", "experts_per_tok")
+# ModelConfig fields a configuration takes from its source unchanged: widths
+# and the architecture's facts
+PUBLISHED = ("d_model", "n_heads", "n_kv_heads", "d_head", "d_ff", "vocab_size",
+             "kv_lora_rank", "q_lora_rank", "rope_head_dim", "nope_head_dim",
+             "v_head_dim", "n_shared_experts", "moe_top_k", "moe_d_ff", "lru_width",
+             "rwkv_head_size", "qk_norm", "tie_embeddings", "rope_theta")
+# fields of scale a cut may reduce, with the configuration file's key for each
+SCALE = (("n_layers", "num_hidden_layers"), ("n_experts", "n_routed_experts"))
 
 
 def _file(name):
+    return json.loads(FILES[name].read_text())
+
+
+def _entry_file(name):
     return json.loads((REPO / CONFIGS[name]["file"]).read_text())
 
 
-@pytest.mark.parametrize("name", sorted(CONFIGS))
+@pytest.mark.parametrize("name", sorted(FILES))
+def test_every_config_names_a_family_that_loads(name):
+    fam = manifest.load_family(REPO, _file(name))
+    for f in ("program_config", "program_params", "score", "prefill_flops", "decode_flops"):
+        assert callable(getattr(fam, f)), f
+
+
+@pytest.mark.parametrize("name", sorted(FILES))
 def test_widths_match_the_program_config(name):
     from repro.configs import get_config
 
     c = _file(name)
+    ours = manifest.load_family(REPO, c).program_config(c)
     prog = get_config(c["program_arch"])
-    m = stack.model_dict(c)
-    assert (m["num_hidden_layers"], m["hidden_size"], m["num_attention_heads"],
-            m["num_key_value_heads"], m["head_dim"], m["intermediate_size"],
-            m["vocab_size"]) == (prog.n_layers, prog.d_model, prog.n_heads,
-                                 prog.n_kv_heads, prog.d_head, prog.d_ff, prog.vocab_size)
-    assert m["qk_norm"] == prog.qk_norm
-    assert m["tie_word_embeddings"] == prog.tie_embeddings
-    assert float(m["rope_theta"]) == prog.rope_theta
+    for f in PUBLISHED:
+        assert getattr(ours, f) == getattr(prog, f), f
+    for f, key in SCALE:
+        if key not in c["reduced"]:
+            assert getattr(ours, f) == getattr(prog, f), f
+    assert ([(s.mixer, s.ffn) for s in ours.segments]
+            == [(s.mixer, s.ffn) for s in prog.segments])
 
 
 @pytest.mark.parametrize("name", sorted(CONFIGS))
 def test_reduced_keys_are_listed_and_no_width(name):
-    c = _file(name)
+    c = _entry_file(name)
     assert sorted(CONFIGS[name]["reduced"]) == sorted(c["reduced"])
     for k in CONFIGS[name]["reduced"]:
         assert not k.endswith(("_dim", "_rank", "_size")), k
         assert not any(w in k for w in WIDTHS), k
-        assert k in c["retrieval"], k
+        assert k in c["model"] or k in c["retrieval"], k
 
 
 @pytest.mark.parametrize("name", sorted(CONFIGS))
@@ -54,13 +77,14 @@ def test_every_cell_names_a_config_and_a_mix(name):
     assert any(cell["config"] == name for cell in MANIFEST["workloads"])
 
 
-@pytest.mark.parametrize("name", sorted(CONFIGS))
+@pytest.mark.parametrize("name", sorted(FILES))
 def test_weights_have_the_program_layout(name):
     from repro.models import lm
 
     c = _file(name)
-    m, cfg = stack.model_dict(c), stack.model_config(c)
-    ours = jax.eval_shape(lambda k: weights._program_params(m, k), jax.random.PRNGKey(0))
+    fam = manifest.load_family(REPO, c)
+    m, cfg = stack.model_dict(c), fam.program_config(c)
+    ours = jax.eval_shape(lambda: fam.program_params(m, 2**32 + 3))
     prog = jax.eval_shape(lambda k: lm.init_params(cfg, k), jax.random.PRNGKey(0))
     assert jax.tree.structure(ours) == jax.tree.structure(prog)
     for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(prog)):

@@ -11,6 +11,7 @@ REPO = Path(__file__).resolve().parents[2]
 TINY_CONFIG = {
     "name": "tiny",
     "source": "https://huggingface.co/Qwen/Qwen3-1.7B/blob/main/config.json",
+    "family": "dense",
     "program_arch": "qwen3-1.7b",
     "model": {"hidden_size": 64, "intermediate_size": 128, "num_hidden_layers": 2,
               "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
@@ -43,7 +44,7 @@ TINY_TRAFFIC = {
 
 def make_root(tmp: Path, config=None, traffic=None) -> Path:
     """A root with BENCHMARK.json naming one tiny cell, the tiny files, and
-    the repository's metric readers."""
+    the repository's model families and metric readers."""
     config = dict(config or TINY_CONFIG)
     traffic = dict(traffic or TINY_TRAFFIC)
     manifest = json.loads((REPO / "BENCHMARK.json").read_text())
@@ -57,8 +58,9 @@ def make_root(tmp: Path, config=None, traffic=None) -> Path:
         m["workloads"] = [cell]
     (tmp / "bench" / "configs").mkdir(parents=True)
     (tmp / "bench" / "traffic").mkdir(parents=True)
-    shutil.copytree(REPO / "bench" / "metrics", tmp / "bench" / "metrics",
-                    ignore=shutil.ignore_patterns("__pycache__"))
+    for d in ("families", "metrics"):
+        shutil.copytree(REPO / "bench" / d, tmp / "bench" / d,
+                        ignore=shutil.ignore_patterns("__pycache__"))
     (tmp / "BENCHMARK.json").write_text(json.dumps(manifest))
     (tmp / "bench" / "configs" / f"{config['name']}.json").write_text(json.dumps(config))
     (tmp / "bench" / "traffic" / f"{traffic['name']}.json").write_text(json.dumps(traffic))
